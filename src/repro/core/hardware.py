@@ -15,6 +15,7 @@ degenerate "platform" used when wall-clock measuring on this container.
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import Dict
 
 
@@ -159,3 +160,32 @@ def get_chip(name: str) -> ChipSpec:
         return CHIPS[name]
     except KeyError:
         raise KeyError(f"unknown chip {name!r}; known: {sorted(CHIPS)}") from None
+
+
+# ``jax.devices()[0].device_kind`` of each generation -> its CHIPS entry.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v4": "tpu_v4",
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v5": "tpu_v5p",
+    "TPU v6 lite": "tpu_v6e",
+}
+
+
+def chip_for_device_kind(kind: str) -> ChipSpec:
+    """The ChipSpec of an attached TPU; a kind missing here is an error,
+    never a silent fallback to another generation's numbers."""
+    try:
+        return CHIPS[DEVICE_KINDS[kind]]
+    except KeyError:
+        raise KeyError(f"no ChipSpec for device_kind {kind!r}; known: "
+                       f"{sorted(DEVICE_KINDS)}") from None
+
+
+def default_chip() -> ChipSpec:
+    """The chip the process tunes for: on a TPU backend the attached
+    device's, otherwise ``$REPRO_TARGET_CHIP`` (default tpu_v5e) — the
+    modelled target of CPU-host tuning."""
+    import jax
+    if jax.default_backend() == "tpu":
+        return chip_for_device_kind(jax.devices()[0].device_kind)
+    return get_chip(os.environ.get("REPRO_TARGET_CHIP", PRODUCTION_CHIP))

@@ -46,10 +46,11 @@ thread pool sharing one compile pool, and ``start_background_tuning``
 spawns the daemon worker that drains the ``TuningQueue`` during idle time
 so ``on_miss="heuristic"`` converges in serving (wired by launch/serve.py).
 
-The module-level ``default_tuner()`` targets ``$REPRO_TARGET_CHIP`` (default
-tpu_v5e) with the analytical backend so model code autotunes deterministically
-on this container; tests and benchmarks construct explicit tuners with
-wall-clock backends.
+The module-level ``default_tuner()`` tunes with the analytical backend for
+``hardware.default_chip()``: the attached chip on a TPU backend (keyed by
+``device_kind``), ``$REPRO_TARGET_CHIP`` (default tpu_v5e) on a CPU host, so
+model code autotunes deterministically; tests and benchmarks construct
+explicit tuners with wall-clock backends.
 """
 
 from __future__ import annotations
@@ -71,7 +72,7 @@ from repro.core import measure as measure_lib
 from repro.core import search as search_lib
 from repro.core.config_space import Config, ConfigSpace, TuningContext
 from repro.core.costmodel import KernelWorkload
-from repro.core.hardware import get_chip
+from repro.core.hardware import default_chip
 from repro.obs import trace as trace_lib
 
 log = logging.getLogger("repro.tuner")
@@ -171,7 +172,7 @@ class Autotuner:
         assert config_source in ("db", "portfolio", "tune")
         self.cache = cache if cache is not None else cache_lib.TuningCache()
         self.backend = backend or measure_lib.AnalyticalMeasure(
-            get_chip(os.environ.get("REPRO_TARGET_CHIP", "tpu_v5e")))
+            default_chip())
         self.strategy = strategy or search_lib.ExhaustiveSearch()
         self.on_miss = on_miss
         # "A Few Fit Most" portfolio (core/portfolio.py). config_source:

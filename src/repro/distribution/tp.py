@@ -36,13 +36,13 @@ i owns rows i·f/tp:(i+1)·f/tp) matches exactly.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distribution.sharding import mesh_signature, tensor_parallel
 from repro.models.config import ModelConfig
@@ -75,11 +75,7 @@ def make_tp_mesh(tp: int) -> Mesh:
             f"tp={tp} but only {n} jax device(s); on a CPU host set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={tp} "
             f"before importing jax")
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh((tp,), (TP_AXIS,))
-    return jax.make_mesh((tp,), (TP_AXIS,),
-                         axis_types=(axis_type.Auto,))
+    return jax.make_mesh((tp,), (TP_AXIS,), axis_types=(AxisType.Auto,))
 
 
 def tp_degree(mesh: Mesh) -> int:
@@ -167,10 +163,30 @@ def _swiglu_wi_permutation(f2: int, tp: int) -> np.ndarray:
         for i in range(tp)])
 
 
+def _named(specs, mesh: Mesh):
+    return jax.tree.map(lambda spec: NamedSharding(mesh, spec), specs,
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+def param_shardings(cfg: ModelConfig, mesh: Mesh):
+    """NamedSharding tree matching ``lm.lm_specs(cfg)`` — hand it to
+    ``init_params`` to create weights already sharded."""
+    return _named(param_partition_specs(cfg), mesh)
+
+
+def _interleave_swiglu(wi, tp: int):
+    """``wi[..., _swiglu_wi_permutation(f2, tp)]`` as a reshape/swap."""
+    *lead, f2 = wi.shape
+    w = wi.reshape(*lead, 2, tp, f2 // (2 * tp))
+    return jnp.swapaxes(w, -3, -2).reshape(*lead, f2)
+
+
 def shard_params(params, cfg: ModelConfig, mesh: Mesh):
     """Lay the parameter tree out for TP: permute swiglu wi columns (see
-    module docstring) and device_put every leaf with its NamedSharding.
-    Returns a new global tree — pass it to the make_tp_* step functions."""
+    module docstring) and device_put every leaf with its NamedSharding
+    (a no-op for leaves ``init_params`` already created with
+    ``param_shardings``). Returns a new global tree — pass it to the
+    make_tp_* step functions."""
     tp = tp_degree(mesh)
     check_tp_supported(cfg, tp)
     specs = param_partition_specs(cfg)
@@ -180,20 +196,26 @@ def shard_params(params, cfg: ModelConfig, mesh: Mesh):
     out = []
     for (path, leaf), spec in zip(flat, sflat):
         keys = [str(p.key) for p in path if hasattr(p, "key")]
+        sharding = NamedSharding(mesh, spec)
         if cfg.act == "swiglu" and len(keys) >= 2 and \
                 keys[-2] == "ffn" and keys[-1] == "wi" and tp > 1:
-            perm = _swiglu_wi_permutation(leaf.shape[-1], tp)
-            leaf = jnp.take(leaf, jnp.asarray(perm), axis=-1)
-        out.append(jax.device_put(leaf, NamedSharding(mesh, spec)))
+            # Reshape/swap instead of a gather: on a leaf that is already
+            # ff-sharded it lowers to an all-to-all, not a full copy of
+            # the leaf on every device.
+            out.append(jax.jit(functools.partial(_interleave_swiglu, tp=tp),
+                               out_shardings=sharding)(leaf))
+        else:
+            out.append(jax.device_put(leaf, sharding))
     return jax.tree_util.tree_unflatten(tdef, out)
 
 
-def shard_cache(cache, mesh: Mesh):
-    """device_put a cache pytree against its TP partition specs."""
-    specs = cache_partition_specs(cache)
-    return jax.tree.map(
-        lambda leaf, spec: jax.device_put(leaf, NamedSharding(mesh, spec)),
-        cache, specs)
+def init_cache(cache_specs, mesh: Mesh):
+    """Zero-filled cache pytree for ``cache_specs`` (ShapeDtypeStructs),
+    created directly in its TP layout: each device allocates only its
+    kv-head shard, never the whole pool."""
+    return jax.jit(lambda: jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype), cache_specs),
+        out_shardings=_named(cache_partition_specs(cache_specs), mesh))()
 
 
 # ---------------------------------------------------------------------------
@@ -210,8 +232,8 @@ def _wrap(cfg: ModelConfig, mesh: Mesh, body_of, in_specs, out_specs):
         with tensor_parallel(TP_AXIS, sig):
             return body_of(lcfg)(*args)
 
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _dense_cache_specs(cfg: ModelConfig, opts) -> Any:

@@ -18,8 +18,10 @@ ConfigSpace/workload/runner in ops.py, and ``register()`` it — the tuner,
 tests, benchmarks, and serving pick it up with no further wiring
 (DESIGN.md §1).
 
-All kernels run under interpret=True on this CPU container (validated
-against ref.py in tests/); on a TPU host the same calls lower via Mosaic.
+Every kernel takes ``interpret=None`` and resolves it in ``mode.py``:
+compiled through Mosaic on a TPU backend, the Pallas interpreter elsewhere
+(the CPU tests validate them against ref.py that way). An explicit
+``interpret=`` wins.
 """
 
 from repro.kernels import ops, ref, registry  # noqa: F401

@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import resolve_interpret
+
 NEG_INF = -1e30
 LANES = 128
 
@@ -93,7 +95,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                      kv_len: Optional[jnp.ndarray] = None,
                      scale: Optional[float] = None,
                      block_kv: int = 512, k_splits: int = 4,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """q (B, Hq, D); k, v (B, Hkv, T, D); kv_len optional (B,) int32."""
     B, Hq, D = q.shape
     _, Hkv, T, _ = k.shape
@@ -148,7 +150,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((group, LANES), jnp.float32),
             pltpu.VMEM((group, LANES), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lens, qg, kp, vp)
 
     # ---- combine the k_splits partial results with logsumexp weights ------

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import resolve_interpret
+
 NEG_INF = -1e30
 LANES = 128
 
@@ -105,7 +107,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: Optional[int] = None,
                     scale: Optional[float] = None, q_offset: int = 0,
                     block_q: int = 128, block_kv: int = 256,
-                    interpret: bool = True,
+                    interpret: Optional[bool] = None,
                     return_lse: bool = False):
     """Flash attention. q (B,Hq,Sq,D); k,v (B,Hkv,Skv,D). GQA via Hq%Hkv==0.
 
@@ -161,7 +163,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, kp, vp)
 
     o = o.reshape(B, Hq, sq_pad, D)[:, :, :Sq]

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import resolve_interpret
+
 NEG_INF = -1e30
 LANES = 128
 
@@ -159,7 +161,7 @@ def _pad_to(x, axis, size):
 def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True,
                         window: Optional[int] = None, scale=None,
                         q_offset: int = 0, block_q: int = 128,
-                        block_kv: int = 128, interpret: bool = True
+                        block_kv: int = 128, interpret: Optional[bool] = None
                         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Gradients (dq, dk, dv). q/o/do (B,Hq,Sq,D); k,v (B,Hkv,Skv,D);
     lse (B,Hq,Sq)."""
@@ -231,7 +233,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True,
             pltpu.VMEM((block_kv, D), jnp.float32),
             pltpu.VMEM((block_kv, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, kp, vp, dop, lsep, deltap)
 
     # --- dq -----------------------------------------------------------------
@@ -254,7 +256,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True,
                                lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, sq_p, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, kp, vp, dop, lsep, deltap)
 
     dq = dq.reshape(B, Hq, sq_p, D)[:, :, :Sq]

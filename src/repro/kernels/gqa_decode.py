@@ -36,6 +36,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.decode_attention import _decode_kernel, _pad_axis, \
     _round_up
+from repro.kernels.mode import resolve_interpret
 
 LANES = 128
 
@@ -45,7 +46,7 @@ def gqa_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                scale: Optional[float] = None,
                block_kv: int = 512, k_splits: int = 1,
                pack_gqa: bool = True,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: Optional[bool] = None) -> jnp.ndarray:
     """q (B, Hq, D); k, v (B, Hkv, T, D); kv_len optional (B,) int32."""
     B, Hq, D = q.shape
     _, Hkv, T, _ = k.shape
@@ -108,7 +109,7 @@ def gqa_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((g, LANES), jnp.float32),
             pltpu.VMEM((g, LANES), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lens, qg, kp, vp)
 
     # ---- combine the k_splits partial results with logsumexp weights ------

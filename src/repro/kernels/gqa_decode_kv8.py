@@ -36,6 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.decode_attention import _pad_axis, _round_up
+from repro.kernels.mode import resolve_interpret
 
 NEG_INF = -1e30
 LANES = 128
@@ -99,7 +100,7 @@ def gqa_decode_kv8(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    scale: Optional[float] = None,
                    block_kv: int = 512, k_splits: int = 1,
                    pack_gqa: bool = True,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: Optional[bool] = None) -> jnp.ndarray:
     """q (B, Hq, D) float; k, v (B, Hkv, T, D) int8; k_scale, v_scale
     (B, Hkv, T) f32; kv_len optional (B,) int32."""
     B, Hq, D = q.shape
@@ -173,7 +174,7 @@ def gqa_decode_kv8(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((g, LANES), jnp.float32),
             pltpu.VMEM((g, LANES), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lens, qg, kp, vp, ksp, vsp)
 
     # ---- combine the k_splits partial results with logsumexp weights ------

@@ -13,11 +13,14 @@ budget, which is exactly the cross-generation portability story.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.mode import resolve_interpret
 
 
 def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, n_k: int):
@@ -38,7 +41,7 @@ def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, n_k: int):
 
 def matmul(x: jnp.ndarray, y: jnp.ndarray, *, block_m: int = 256,
            block_n: int = 256, block_k: int = 256,
-           interpret: bool = True) -> jnp.ndarray:
+           interpret: Optional[bool] = None) -> jnp.ndarray:
     """x (M, K) @ y (K, N) with fp32 accumulation."""
     M, K = x.shape
     K2, N = y.shape
@@ -61,7 +64,7 @@ def matmul(x: jnp.ndarray, y: jnp.ndarray, *, block_m: int = 256,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xp, yp)
     return out[:M, :N]
 

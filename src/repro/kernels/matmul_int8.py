@@ -38,11 +38,14 @@ model sees through ``ChipSpec.flops_for_dtype``).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.mode import resolve_interpret
 
 
 def _epilogue_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
@@ -95,7 +98,7 @@ def matmul_w8a8(x: jnp.ndarray, w: jnp.ndarray, x_scale: jnp.ndarray,
                 w_scale: jnp.ndarray, *, block_m: int = 256,
                 block_n: int = 256, block_k: int = 512,
                 dequant: str = "epilogue", scale_gran: str = "per_channel",
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: Optional[bool] = None) -> jnp.ndarray:
     """x (M, K) int8 @ w (K, N) int8 → (M, N) float32, scales fused.
 
     ``x_scale`` is (M,)/(M, 1) per-row or scalar; ``w_scale`` is
@@ -153,7 +156,7 @@ def matmul_w8a8(x: jnp.ndarray, w: jnp.ndarray, x_scale: jnp.ndarray,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), acc_dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xp, wp, xs, ws)
     return out[:M, :N]
 

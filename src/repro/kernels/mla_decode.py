@@ -35,6 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.decode_attention import _pad_axis, _round_up
+from repro.kernels.mode import resolve_interpret
 
 NEG_INF = -1e30
 LANES = 128
@@ -103,7 +104,8 @@ def _mla_decode_kernel(len_ref, qa_ref, qr_ref, ckv_ref, kr_ref,  # inputs
 def mla_decode(q_abs: jnp.ndarray, q_rope: jnp.ndarray, ckv: jnp.ndarray,
                krope: jnp.ndarray, *, kv_len: Optional[jnp.ndarray] = None,
                scale: Optional[float] = None, block_kv: int = 512,
-               k_splits: int = 1, interpret: bool = True) -> jnp.ndarray:
+               k_splits: int = 1,
+               interpret: Optional[bool] = None) -> jnp.ndarray:
     """Absorbed-MLA decode over the compressed cache.
 
     q_abs (B, H, C) — queries with W_uk absorbed; q_rope (B, H, R);
@@ -160,7 +162,7 @@ def mla_decode(q_abs: jnp.ndarray, q_rope: jnp.ndarray, ckv: jnp.ndarray,
             pltpu.VMEM((H, LANES), jnp.float32),
             pltpu.VMEM((H, LANES), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lens, q_abs, q_rope, ckv_p, kr_p)
 
     # ---- combine the k_splits partial results with logsumexp weights ------

@@ -162,8 +162,8 @@ def _ctx(tuner: Autotuner, shapes: Dict[str, Tuple[int, ...]], dtype: str,
     if chip is None:
         chip = getattr(getattr(tuner.backend, "analytical", None), "chip", None)
     if chip is None:
-        from repro.core.hardware import get_chip
-        chip = get_chip("tpu_v5e")
+        from repro.core.hardware import default_chip
+        chip = default_chip()
     # Inside a tensor_parallel shard_map body the entry points trace with
     # per-shard LOCAL shapes; stamping the mesh signature keeps those tuning
     # scenarios (and their cached winners) distinct from an unsharded model
@@ -300,7 +300,7 @@ def _flash_runner(cfg: Config, ctx: TuningContext):
 
 
 def _flash_dispatch(q, k, v, *, causal, window, config, q_offset=0,
-                    interpret=True, return_lse=False):
+                    interpret=None, return_lse=False):
     from repro.kernels.flash_attention import flash_attention
     D = q.shape[-1]
     cfg = dict(config)
@@ -334,7 +334,8 @@ FLASH_ATTENTION = TunableKernel(
 
 def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
               q_offset: int = 0, config: Optional[Config] = None,
-              tuner: Optional[Autotuner] = None, interpret: bool = True,
+              tuner: Optional[Autotuner] = None,
+              interpret: Optional[bool] = None,
               return_lse: bool = False):
     """Autotuned flash attention. q (B,Hq,Sq,D); k,v (B,Hkv,Skv,D)."""
     if config is None:
@@ -409,7 +410,8 @@ FLASH_ATTENTION_BWD = TunableKernel(
 
 def attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
                   config: Optional[Config] = None,
-                  tuner: Optional[Autotuner] = None, interpret: bool = True):
+                  tuner: Optional[Autotuner] = None,
+                  interpret: Optional[bool] = None):
     """Autotuned flash-attention gradients (dq, dk, dv). Layout (B,H,S,D)."""
     from repro.kernels.flash_attention_bwd import flash_attention_bwd
     if config is None:
@@ -515,7 +517,8 @@ DECODE_ATTENTION = TunableKernel(
 
 
 def decode(q, k, v, *, kv_len=None, config: Optional[Config] = None,
-           tuner: Optional[Autotuner] = None, interpret: bool = True):
+           tuner: Optional[Autotuner] = None,
+           interpret: Optional[bool] = None):
     """Autotuned decode attention. q (B,Hq,D); k,v (B,Hkv,T,D)."""
     from repro.kernels.decode_attention import decode_attention
     if config is None:
@@ -632,7 +635,8 @@ GQA_DECODE_RAGGED = TunableKernel(
 
 
 def ragged_decode(q, k, v, *, kv_len=None, config: Optional[Config] = None,
-                  tuner: Optional[Autotuner] = None, interpret: bool = True):
+                  tuner: Optional[Autotuner] = None,
+                  interpret: Optional[bool] = None):
     """Autotuned ragged GQA decode. q (B,Hq,D); k,v (B,Hkv,T,D);
     kv_len (B,) int32 per-request valid lengths."""
     from repro.kernels.gqa_decode import gqa_decode as gqa_kernel
@@ -811,7 +815,8 @@ def paged_decode(q, k_pages, v_pages, block_tables, kv_len, *,
                  k_scales=None, v_scales=None,
                  scale: Optional[float] = None,
                  config: Optional[Config] = None,
-                 tuner: Optional[Autotuner] = None, interpret: bool = True):
+                 tuner: Optional[Autotuner] = None,
+                 interpret: Optional[bool] = None):
     """Autotuned paged decode. q (B,Hq,D); k/v_pages (Hkv,P,page_size,D);
     block_tables (B,max_pages) int32; kv_len (B,) int32. Int8 pools (the
     kv8 policy) pass per-token ``k_scales``/``v_scales``
@@ -1028,7 +1033,8 @@ def paged_verify(q, k_pages, v_pages, block_tables, kv_len, *,
                  k_scales=None, v_scales=None,
                  scale: Optional[float] = None,
                  config: Optional[Config] = None,
-                 tuner: Optional[Autotuner] = None, interpret: bool = True):
+                 tuner: Optional[Autotuner] = None,
+                 interpret: Optional[bool] = None):
     """Autotuned speculative verify. q (B,K,Hq,D) — K consecutive query
     positions per sequence; k/v_pages (Hkv,P,page_size,D);
     block_tables (B,max_pages) int32; kv_len (B,) int32 valid tokens
@@ -1183,7 +1189,8 @@ MLA_DECODE = TunableKernel(
 def latent_decode(q_abs, q_rope, ckv, krope, *, kv_len=None,
                   scale: Optional[float] = None,
                   config: Optional[Config] = None,
-                  tuner: Optional[Autotuner] = None, interpret: bool = True):
+                  tuner: Optional[Autotuner] = None,
+                  interpret: Optional[bool] = None):
     """Autotuned absorbed-MLA decode. q_abs (B,H,C); q_rope (B,H,R);
     ckv (B,T,C); krope (B,T,R). Returns attended latents (B,H,C) f32."""
     from repro.kernels.mla_decode import mla_decode as mla_kernel
@@ -1258,7 +1265,8 @@ def _rms_runner(cfg: Config, ctx: TuningContext):
 
 
 def rmsnorm(x, weight, *, eps: float = 1e-6, config: Optional[Config] = None,
-            tuner: Optional[Autotuner] = None, interpret: bool = True):
+            tuner: Optional[Autotuner] = None,
+            interpret: Optional[bool] = None):
     from repro.kernels.rms_norm import rms_norm
     if config is None:
         tuner = tuner or default_tuner()
@@ -1343,7 +1351,8 @@ MATMUL = TunableKernel(
 
 
 def matmul(x, y, *, config: Optional[Config] = None,
-           tuner: Optional[Autotuner] = None, interpret: bool = True):
+           tuner: Optional[Autotuner] = None,
+           interpret: Optional[bool] = None):
     from repro.kernels.matmul import matmul as mm
     if config is None:
         tuner = tuner or default_tuner()
@@ -1455,7 +1464,8 @@ MATMUL_W8A8 = TunableKernel(
 
 
 def matmul_w8a8(x, w, x_scale, w_scale, *, config: Optional[Config] = None,
-                tuner: Optional[Autotuner] = None, interpret: bool = True):
+                tuner: Optional[Autotuner] = None,
+                interpret: Optional[bool] = None):
     """Autotuned w8a8 GEMM. x (M,K) int8; w (K,N) int8; x_scale (M,1) or
     scalar; w_scale (1,N) or scalar. Returns (M,N) float32 with the
     calibration scales fused into the kernel."""
@@ -1572,7 +1582,7 @@ GQA_DECODE_KV8 = TunableKernel(
 def ragged_decode_kv8(q, k, v, k_scale, v_scale, *, kv_len=None,
                       config: Optional[Config] = None,
                       tuner: Optional[Autotuner] = None,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """Autotuned int8-KV ragged decode. q (B,Hq,D) float; k, v
     (B,Hkv,T,D) int8; k_scale, v_scale (B,Hkv,T) f32 per-token scales;
     kv_len (B,) int32 valid lengths."""

@@ -47,6 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import resolve_interpret
+
 NEG_INF = -1e30
 LANES = 128
 
@@ -86,14 +88,14 @@ def _paged_kernel(tbl_ref, len_ref,                # scalar-prefetched
         q = q_ref[0].astype(jnp.float32)            # (g, D)
         k = k_ref[0, 0].astype(jnp.float32)         # (page_size, D)
         v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            # int8 pages: dequantize rows by their per-token scales (the
-            # kv8 policy — scales live in parallel scale pools).
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (g, page_size)
+        if quantized:
+            # int8 pages (the kv8 policy): per-token scales arrive as one
+            # lane-major (1, page_size) row, so they dequantize the scores
+            # column-wise instead of the (page_size, D) rows of K.
+            s = s * ks_ref[0, 0]
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(k_pos < kv_len, s, NEG_INF)
 
@@ -103,8 +105,10 @@ def _paged_kernel(tbl_ref, len_ref,                # scalar-prefetched
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        # Likewise V's per-token scales weight the probabilities.
+        pv = p * vs_ref[0, 0] if quantized else p
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -123,7 +127,7 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                  scale: Optional[float] = None,
                  block_kv: Optional[int] = None,
                  pack_gqa: bool = True,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """Block-table-indexed decode attention over a shared page pool.
 
     q            (B, Hq, D)   one query token per sequence
@@ -175,9 +179,6 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     def kv_index(r, sj, pj, tbl, lens, ppb=pages_per_block):
         return (kv_head(r), tbl[r // heads_per_b, sj * ppb + pj], 0, 0)
 
-    def scale_index(r, sj, pj, tbl, lens, ppb=pages_per_block):
-        return (kv_head(r), tbl[r // heads_per_b, sj * ppb + pj], 0)
-
     in_specs = [
         pl.BlockSpec((1, g, D), lambda r, sj, pj, tbl, lens: (r, 0, 0)),
         pl.BlockSpec((1, 1, page_size, D), kv_index),
@@ -185,10 +186,12 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, page_size), scale_index),
-                     pl.BlockSpec((1, 1, page_size), scale_index)]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
+        # Scale pools go in as (Hkv, P, 1, page_size): a (1, page_size)
+        # block spans whole trailing dims, which Mosaic requires of a
+        # block that is not (8, 128)-aligned.
+        in_specs += [pl.BlockSpec((1, 1, 1, page_size), kv_index)] * 2
+        operands += [sc.astype(jnp.float32)[:, :, None, :]
+                     for sc in (k_scales, v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(rows, n_super, pages_per_block),
@@ -209,7 +212,7 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, g, D), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
       *operands)
     return o.reshape(B, Hq, D).astype(q.dtype)

@@ -15,10 +15,13 @@ space, which the vmem_fits constraint enforces per chip.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.mode import resolve_interpret
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float, n_rows: int,
@@ -30,7 +33,8 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float, n_rows: int,
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, *, eps: float = 1e-6,
-             block_rows: int = 128, interpret: bool = True) -> jnp.ndarray:
+             block_rows: int = 128,
+             interpret: Optional[bool] = None) -> jnp.ndarray:
     """x (..., D) → RMS-normalized, scaled by weight (D,)."""
     orig_shape = x.shape
     D = x.shape[-1]
@@ -52,7 +56,7 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, D), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x2, weight.reshape(1, D))
     return out[:N].reshape(orig_shape)
 

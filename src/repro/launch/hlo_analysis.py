@@ -157,12 +157,8 @@ class _Comp:
                 if not hits:
                     continue
                 uses += len(hits)
-                # First dynamic-slice operand may carry an inline type
-                # ("dynamic-slice(f32[...]{...} %p, ..." — older jax).
                 dm = re.search(
-                    r"dynamic-slice\("
-                    r"(?:[a-z]\w*\[[\d,]*\](?:\{[\d,]*\})?\s+)?%?" +
-                    re.escape(pname) +
+                    r"dynamic-slice\(%?" + re.escape(pname) +
                     r"\b.*dynamic_slice_sizes=\{([\d,]+)\}", rhs)
                 if dm:
                     dims = [int(d) for d in dm.group(1).split(",")]
@@ -213,12 +209,8 @@ _TRIP_RE = re.compile(r'known_trip_count[^0-9]*(\d+)')
 
 
 def _operand_names(inner: str) -> List[str]:
-    """Operand symbol names from an op's argument list. Handles both dump
-    formats: symbol-only ("%a, %b") and inline-typed
-    ("f32[8,16]{1,0} %a, f32[16]{0} %b" — older jax)."""
-    if "%" in inner:
-        return re.findall(r"%([\w\.\-_]+)", inner)
-    return [o.strip().split(" ")[-1] for o in inner.split(",") if o.strip()]
+    """Operand symbol names from an op's argument list ("%a, %b")."""
+    return re.findall(r"%([\w\.\-_]+)", inner)
 
 
 def _dot_flops(line: str, comp: _Comp) -> float:

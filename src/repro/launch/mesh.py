@@ -12,13 +12,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older jax builds the same
-    # (fully Auto) mesh with no axis_types argument.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

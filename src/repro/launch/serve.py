@@ -66,6 +66,7 @@ import numpy as np
 
 from repro.configs import ARCHS, get_config
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import lm
 from repro.models.param import init_params
@@ -75,7 +76,8 @@ from repro.obs.metrics import default_registry
 
 
 def serve_paged(args, cfg, tuner):
-    """Continuous batching over a paged KV pool."""
+    """Continuous batching over a paged KV pool. Returns the engine, the
+    requests it served and ``ServingEngine.run``'s result."""
     from repro.core.config_space import TuningContext
     from repro.quant import get_policy
     from repro.serving import Request, ServingEngine
@@ -149,7 +151,12 @@ def serve_paged(args, cfg, tuner):
         drift = drift_lib.DriftDetector()
         drift_lib.set_active(drift)        # eager ops.py dispatches too
 
-    params = init_params(jax.random.PRNGKey(0), lm.lm_specs(cfg))
+    shardings = None
+    if args.tp > 1:
+        # Weights are drawn already sharded, never whole on one device.
+        from repro.distribution import tp as tp_lib
+        shardings = tp_lib.param_shardings(cfg, tp_lib.make_tp_mesh(args.tp))
+    params = init_params(jax.random.PRNGKey(0), lm.lm_specs(cfg), shardings)
     rng = np.random.default_rng(0)
     pages_per_seq = -(-(max_seq_len + args.prefill_chunk) // page_size)
     engine = ServingEngine(
@@ -276,6 +283,7 @@ def serve_paged(args, cfg, tuner):
         rep = drift.report()
         print(f"drift: {rep['tracked_keys']} keys tracked, "
               f"{rep['flagged_keys']} flagged -> {args.drift_report}")
+    return engine, reqs, res
 
 
 def serve_dense(args, cfg):
@@ -434,6 +442,7 @@ def main(argv=None):
                          "through the paged serving engine)")
     os.environ["REPRO_ON_MISS"] = args.on_miss
     os.environ["REPRO_CONFIG_SOURCE"] = args.config_source
+    print(f"jax compilation cache: {enable_compile_cache()}")
     cfg = get_config(args.arch, smoke=not args.full_config)
     if args.decode_impl != "full":
         from repro.kernels.registry import list_kernels
@@ -469,8 +478,9 @@ def main(argv=None):
         tuner.start_background_tuning()
         print("background tuning worker started (queue drains off the "
               "critical path)")
+    out = None
     if args.decode_impl == "paged":
-        serve_paged(args, cfg, tuner)
+        out = serve_paged(args, cfg, tuner)
     else:
         serve_dense(args, cfg)
     if tuner.on_miss == "heuristic":
@@ -483,6 +493,7 @@ def main(argv=None):
             time.sleep(0.1)
         tuner.stop_background_tuning(timeout=30.0)
         print(f"tuner stats: {tuner.stats()} (queue left: {len(tuner.queue)})")
+    return out
 
 
 if __name__ == "__main__":

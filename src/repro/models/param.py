@@ -58,12 +58,33 @@ def _init_leaf(key, spec: ParamSpec):
         spec.dtype)
 
 
-def init_params(rng, specs):
+_init_leaf_jit = jax.jit(_init_leaf, static_argnames="spec")
+
+
+def _draw_leaf(key, spec: ParamSpec, sharding=None):
+    if sharding is not None:
+        return jax.jit(_init_leaf, static_argnames="spec",
+                       out_shardings=sharding)(key, spec=spec)
+    if jnp.dtype(spec.dtype) == jnp.float32:
+        return _init_leaf(key, spec)      # the draw already is the leaf
+    # One program per narrower leaf fuses the float32 draw into the cast:
+    # eagerly, a (32, 3072, 16384) bf16 leaf would first exist as 6.4 GB
+    # of float32.
+    return _init_leaf_jit(key, spec=spec)
+
+
+def init_params(rng, specs, shardings=None):
+    """Random weights for ``specs``. With a ``shardings`` tree (one
+    ``jax.sharding.Sharding`` per leaf) every leaf is created already laid
+    out, never whole on one device first."""
     leaves, treedef = jax.tree.flatten(
         specs, is_leaf=lambda x: isinstance(x, ParamSpec))
     keys = jax.random.split(rng, len(leaves))
+    outs = (jax.tree.leaves(shardings) if shardings is not None
+            else [None] * len(leaves))
+    assert len(outs) == len(leaves), "param tree / sharding tree mismatch"
     return jax.tree.unflatten(
-        treedef, [_init_leaf(k, s) for k, s in zip(keys, leaves)])
+        treedef, [_draw_leaf(k, s, o) for k, s, o in zip(keys, leaves, outs)])
 
 
 def shape_tree(specs):

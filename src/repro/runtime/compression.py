@@ -71,11 +71,9 @@ def compressed_psum_mean(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
 
 def make_compressed_allreduce(mesh, axis: str = "data"):
     """jit-able f(tree) → tree mean-reduced over ``axis`` via int8 wire."""
-    from jax.experimental.shard_map import shard_map
-
     def reduce_tree(tree):
         def per_leaf(x):
-            fn = shard_map(
+            fn = jax.shard_map(
                 functools.partial(compressed_psum_mean, axis_name=axis),
                 mesh=mesh,
                 in_specs=P(axis),

@@ -854,8 +854,6 @@ class ServingEngine:
             params, policy,
             store="grid" if self.opts.quant_impl == "sim" else "int8")
         kv_dtype = policy.kv_dtype if policy is not None else None
-        self.cache = lm.init_paged_cache(cfg, num_pages, page_size,
-                                         kv_dtype=kv_dtype)
         self._jax = jax
         self._jnp = jnp
 
@@ -869,7 +867,9 @@ class ServingEngine:
                     "param sharding; use tp=1 or the kv8 policy")
             self.mesh = tp_lib.make_tp_mesh(self.tp)
             self.params = tp_lib.shard_params(self.params, cfg, self.mesh)
-            self.cache = tp_lib.shard_cache(self.cache, self.mesh)
+            self.cache = tp_lib.init_cache(
+                lm.paged_cache_specs(cfg, num_pages, page_size,
+                                     kv_dtype=kv_dtype), self.mesh)
             step_prefill = tp_lib.make_tp_prefill_paged(cfg, self.mesh,
                                                         opts=self.opts)
             step_decode = tp_lib.make_tp_decode_paged(cfg, self.mesh,
@@ -878,6 +878,9 @@ class ServingEngine:
                                                        opts=self.opts)
                            if self.spec_k > 1 else None)
         else:
+            self.cache = lm.init_paged_cache(cfg, num_pages, page_size,
+                                             kv_dtype=kv_dtype)
+
             def step_prefill(params, tokens, cache, tables, start):
                 return lm.prefill_paged(params, cfg, tokens, cache,
                                         tables, start, self.opts)

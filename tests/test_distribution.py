@@ -51,8 +51,6 @@ def test_spec_divisibility_fallback():
 
 def test_spec_pod_prefix_fallback():
     # batch 8 divisible by pod(2)·data(16)? No (32∤8) → try prefix (pod,)=2 ✓
-    # singleton tuples are unwrapped so the spec compares equal on every
-    # jax version (newer jax normalizes P(("pod",)) to P("pod") anyway)
     assert spec_for((8, 128), ("batch", None), TP, MESH3) == P("pod")
 
 
@@ -73,11 +71,6 @@ def test_shard_heads_or_seq_decision():
 # HLO analyzer
 # ---------------------------------------------------------------------------
 
-def _cost_analysis(c):
-    ca = c.cost_analysis()
-    return ca[0] if isinstance(ca, list) else ca   # older jax returns a list
-
-
 def test_hlo_scan_trip_count_flops():
     def f(x, w):
         def body(c, wi):
@@ -91,7 +84,7 @@ def test_hlo_scan_trip_count_flops():
     assert 0.95 < st.flops / expect < 1.15
     assert 12 in st.while_loops.values()
     # XLA's own analysis undercounts (documents why analyze_hlo exists)
-    assert _cost_analysis(c).get("flops", 0) < 0.2 * expect
+    assert c.cost_analysis().get("flops", 0) < 0.2 * expect
 
 
 def test_hlo_control_matches_cost_analysis():
@@ -100,7 +93,7 @@ def test_hlo_control_matches_cost_analysis():
     sds = jax.ShapeDtypeStruct((512, 512), jnp.float32)
     c = jax.jit(g).lower(sds, sds).compile()
     st = analyze_hlo(c.as_text(), 1)
-    ca = _cost_analysis(c)
+    ca = c.cost_analysis()
     assert abs(st.flops - ca["flops"]) / ca["flops"] < 0.02
     assert abs(st.bytes - ca["bytes accessed"]) / ca["bytes accessed"] < 0.1
 

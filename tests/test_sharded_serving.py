@@ -146,6 +146,15 @@ def test_swiglu_wi_permutation_is_shardwise_gate_up():
     assert sorted(perm) == list(range(f2))
 
 
+def test_interleave_swiglu_matches_permutation():
+    import numpy as np
+    f2, tp = 16, 4
+    wi = np.arange(3 * 5 * f2, dtype=np.float32).reshape(3, 5, f2)
+    np.testing.assert_array_equal(
+        np.asarray(tp_lib._interleave_swiglu(jnp.asarray(wi), tp)),
+        wi[..., tp_lib._swiglu_wi_permutation(f2, tp)])
+
+
 # ---------------------------------------------------------------------------
 # Token-for-token equality + mesh-keyed tuning (8 forced host devices)
 # ---------------------------------------------------------------------------
