@@ -213,6 +213,7 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, g, D), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="paged_decode",
     )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
       *operands)
     return o.reshape(B, Hq, D).astype(q.dtype)

@@ -16,6 +16,10 @@ Every scheduler step:
                    exhaustion a victim (latest arrival first) is preempted
                    and re-queued.
 
+``ServingEngine.step`` records each phase as a ``serve.*`` span in the
+profiler's trace, and the scheduler stamps each ``Request`` with its
+submission, admission and prompt completion (docs/observability.md).
+
 Optimistic admission is what makes the pool a real resource: admission no
 longer reserves ``prompt + max_new_tokens`` pages up front, so many more
 requests run concurrently, and the price is that the pool can exhaust
@@ -58,7 +62,6 @@ import enum
 import math
 import time
 from collections import deque
-from contextlib import nullcontext
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -69,8 +72,6 @@ from repro.obs import trace as trace_lib
 from repro.serving import faults as fault_lib
 from repro.serving.page_pool import SCRATCH_PAGE, PagePool
 from repro.serving.prefix_cache import PrefixCache
-
-_NULL_CTX = nullcontext()
 
 # Per-request token-timestamp cap: past this many samples new timestamps
 # are counted in ``token_times_dropped`` instead of appended, so latency
@@ -117,7 +118,14 @@ class Request:
     cancelled: bool = False
     wait_steps: int = 0                # admission aging (head-of-line cap)
     not_before_step: int = 0           # backoff: earliest re-admission step
-    submit_step: int = 0               # scheduler step at submission
+    # Scheduler spans and counters (``time.perf_counter`` seconds, the
+    # clock of ``token_times``), first occupancy only: a resumed request
+    # keeps its first stamps and counts nothing more.
+    submitted_at: Optional[float] = None   # Scheduler.submit
+    admitted_at: Optional[float] = None    # first admission to a slot
+    prefilled_at: Optional[float] = None   # its last prompt chunk ran
+    prefill_chunks: int = 0            # prompt chunks run for it
+    prefill_steps: int = 0             # steps that found prompt tokens left
 
     @property
     def prompt_len(self) -> int:
@@ -176,13 +184,13 @@ class StepStats:
                     or self.timed_out or self.degraded)
 
 
-def latency_summary(requests: List[Request], t0: float) -> Dict[str, Any]:
+def latency_summary(requests: List[Request]) -> Dict[str, Any]:
     """Exact p50/p99 TTFT and inter-token latency (ms) from the recorded
-    ``Request.token_times``. TTFT is first token minus run start ``t0``
-    (arrival is not wall-anchored in untimed replays); inter-token gaps
-    are consecutive-timestamp deltas within each request. Percentiles
-    cover the recorded sample prefix — ``token_times_dropped`` reports
-    what the ``TOKEN_TIMES_CAP`` bound discarded."""
+    ``Request.token_times``. TTFT is first token minus the request's own
+    ``submitted_at``; inter-token gaps are consecutive-timestamp deltas
+    within each request. Percentiles cover the recorded sample prefix —
+    ``token_times_dropped`` reports what the ``TOKEN_TIMES_CAP`` bound
+    discarded."""
     ttfts: List[float] = []
     itls: List[float] = []
     dropped = 0
@@ -190,7 +198,8 @@ def latency_summary(requests: List[Request], t0: float) -> Dict[str, Any]:
         ts = r.token_times
         dropped += r.token_times_dropped
         if ts:
-            ttfts.append((ts[0] - t0) * 1e3)
+            if r.submitted_at is not None:
+                ttfts.append((ts[0] - r.submitted_at) * 1e3)
             itls.extend((b - a) * 1e3 for a, b in zip(ts, ts[1:]))
 
     def pct(xs: List[float], q: float) -> Optional[float]:
@@ -257,11 +266,10 @@ class Scheduler:
         self.events: List[Dict[str, Any]] = []
         self.tracer = tracer
         self.metrics = metrics
-        self._m_queue_delay = (
+        self._m_admit_wait = (
             metrics.histogram(
-                "serving_queue_delay_steps",
-                buckets=(0, 1, 2, 5, 10, 20, 50, 100, 200, 500, 1000),
-                help="scheduler steps between submission and admission")
+                "serving_admit_wait_ms",
+                help="submission to first admission per request (ms)")
             if metrics is not None else None)
 
     def _event(self, op: str, **kw) -> None:
@@ -310,6 +318,7 @@ class Scheduler:
         self._event("reject", rid=req.rid, reason=reason)
 
     def submit(self, req: Request) -> None:
+        req.submitted_at = time.perf_counter()
         if req.prompt_len < 1 or req.max_new_tokens < 1:
             return self.reject(req, "empty prompt or zero generation budget")
         need = self.pool.pages_for(self.max_tokens(req))
@@ -321,7 +330,6 @@ class Scheduler:
                 req, f"needs {need} pages > pool capacity "
                      f"{self.pool.num_pages - 1}")
         req.state = RequestState.QUEUED
-        req.submit_step = self._step
         self.waiting.append(req)
         self._event("submit", rid=req.rid)
 
@@ -541,8 +549,12 @@ class Scheduler:
                               rid=req.rid, resumed=resumed,
                               cached_tokens=cached_tokens,
                               pages=len(all_pages))
-        if self._m_queue_delay is not None:
-            self._m_queue_delay.observe(self._step - req.submit_step)
+        if req.admitted_at is None:
+            req.admitted_at = time.perf_counter()
+            if self._m_admit_wait is not None and \
+                    req.submitted_at is not None:
+                self._m_admit_wait.observe(
+                    (req.admitted_at - req.submitted_at) * 1e3)
         self._event("admit", rid=req.rid, resumed=resumed,
                     cached_tokens=cached_tokens, pages=len(all_pages))
         return True
@@ -626,8 +638,15 @@ class Scheduler:
     def next_prefill(self) -> Optional[Tuple[int, np.ndarray, int, int]]:
         """Pick one sequence with pending prefill tokens (round-robin) and
         cut its next chunk. Returns (slot, padded chunk (C,), start,
-        n_valid) or None."""
+        n_valid) or None. Called once a step: every first-occupancy
+        sequence with prompt tokens left counts the step in
+        ``Request.prefill_steps``, whether or not its chunk is the one
+        cut."""
         c = self.prefill_chunk
+        for seq in self.slots:
+            if seq is not None and not seq.prompt_done \
+                    and seq.req.retries == 0:
+                seq.req.prefill_steps += 1
         for off in range(self.max_batch):
             b = (self._prefill_rr + off) % self.max_batch
             seq = self.slots[b]
@@ -650,6 +669,10 @@ class Scheduler:
         self.total_prefill_tokens += n_valid
         if seq.pos >= len(seq.view):
             seq.prompt_done = True
+        if seq.req.retries == 0:
+            seq.req.prefill_chunks += 1
+            if seq.prompt_done:
+                seq.req.prefilled_at = time.perf_counter()
 
     def decode_mask(self, lookahead: int = 1) -> np.ndarray:
         """Decode-ready slots, after growing every slot's pages to cover
@@ -754,6 +777,25 @@ class Scheduler:
                 f"request {req.rid} finished in state {req.state}"
 
 
+class _Both:
+    """Enter a profiler annotation and a tracer span as one span."""
+
+    __slots__ = ("ann", "span")
+
+    def __init__(self, ann, span):
+        self.ann = ann
+        self.span = span
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.span.__exit__(*exc)
+        return self.ann.__exit__(*exc)
+
+
 class ServingEngine:
     """Binds a model to the scheduler and serves a request list.
 
@@ -839,7 +881,7 @@ class ServingEngine:
             record_events=record_events, spec_k=self.spec_k,
             tracer=tracer, metrics=metrics)
         self.max_seq_len = int(max_seq_len)
-        self._run_t0: Optional[float] = None
+        self.step_num = 0              # engine steps taken
         self._init_metrics()
         if opts is None:
             opts = lm.ForwardOpts(decode_impl="paged", quant=quant)
@@ -856,6 +898,8 @@ class ServingEngine:
         kv_dtype = policy.kv_dtype if policy is not None else None
         self._jax = jax
         self._jnp = jnp
+        self._annotation = jax.profiler.TraceAnnotation
+        self._step_annotation = jax.profiler.StepTraceAnnotation
 
         self.tp = int(tp)
         self.mesh = None
@@ -998,11 +1042,20 @@ class ServingEngine:
         m.register_provider("tuner", _tuner_stats)
         m.register_provider("drift", lambda: dict(self._drift_stats))
 
-    def _span(self, name: str, **args):
-        """Scheduler-phase span on the engine tracer (no-op untraced)."""
+    def _span(self, name: str, step_num: Optional[int] = None, **args):
+        """One host phase of a step as a ``jax.profiler`` annotation, on
+        the profiler's clock when a profile is being taken (a
+        ``StepTraceAnnotation`` when ``step_num`` is given). With a tracer
+        attached the same span is also recorded on its ``scheduler``
+        track."""
+        if step_num is None:
+            ann = self._annotation(name, **args)
+        else:
+            ann = self._step_annotation(name, step_num=step_num, **args)
+            args = dict(step_num=step_num, **args)
         if self.tracer is None:
-            return _NULL_CTX
-        return self.tracer.span(name, track="scheduler", **args)
+            return ann
+        return _Both(ann, self.tracer.span(name, track="scheduler", **args))
 
     def _note_token(self, req: Request, t: float) -> None:
         """Record one generated-token timestamp (bounded) and feed the
@@ -1012,8 +1065,8 @@ class ServingEngine:
         if self.metrics is None:
             return
         if prev is None:
-            if self._run_t0 is not None:
-                self._m_ttft.observe((t - self._run_t0) * 1e3)
+            if req.submitted_at is not None:
+                self._m_ttft.observe((t - req.submitted_at) * 1e3)
         else:
             self._m_itl.observe((t - prev) * 1e3)
 
@@ -1139,7 +1192,22 @@ class ServingEngine:
             self._dev_tables_key = key
         return self._dev_tables
 
-    def _step_verify(self, mask: np.ndarray, plan, stats: StepStats) -> None:
+    def _step_inputs(self, mask: np.ndarray, toks: np.ndarray, plan):
+        """Device inputs of a decode or verify step: tokens, block
+        tables, lengths (0 for inactive slots) and the fault harness's
+        per-slot logit scale."""
+        jnp = self._jnp
+        sched = self.scheduler
+        lens = sched.lens() * mask
+        scale = np.ones((sched.max_batch, 1), np.float32)
+        if plan is not None:
+            active = [int(b) for b in np.nonzero(mask)[0]]
+            for s in plan.logit_poison(sched._step, active):
+                scale[s] = float("nan")
+        return (jnp.asarray(toks), self._dev_tables_for(mask),
+                jnp.asarray(lens, jnp.int32), jnp.asarray(scale))
+
+    def _step_verify(self, plan, stats: StepStats) -> None:
         """One speculative decode step for every ready slot: scatter the
         last committed token plus K-1 n-gram drafts, score all K
         positions in one ``paged_verify`` launch, and commit the
@@ -1158,168 +1226,200 @@ class ServingEngine:
         config is quarantined, and the same tokens are re-scored by
         plain decode next step, so the request still finishes
         token-identically."""
-        jnp = self._jnp
         sched = self.scheduler
         K = self.spec_k
-        toks = np.zeros((sched.max_batch, K), np.int32)
-        for b in np.nonzero(mask)[0]:
-            seq = sched.slots[int(b)]
-            toks[b, 0] = seq.req.tokens[-1]
-            toks[b, 1:] = self._drafter(seq.req).propose(K - 1)
-        lens = sched.lens() * mask                # inactive slots -> 0
-        scale = np.ones((sched.max_batch, 1), np.float32)
-        if plan is not None:
-            active = [int(b) for b in np.nonzero(mask)[0]]
-            for s in plan.logit_poison(sched._step, active):
-                scale[s] = float("nan")
+        with self._span("serve.verify.prepare"):
+            mask = sched.decode_mask(lookahead=K)
+            if not mask.any():
+                return
+            toks = np.zeros((sched.max_batch, K), np.int32)
+            for b in np.nonzero(mask)[0]:
+                seq = sched.slots[int(b)]
+                toks[b, 0] = seq.req.tokens[-1]
+                toks[b, 1:] = self._drafter(seq.req).propose(K - 1)
+            toks_d, tables, lens_d, scale_d = self._step_inputs(
+                mask, toks, plan)
         log_n = len(plan.log) if plan is not None else 0
         det = self._drift_detector()
-        t_disp = time.perf_counter()
-        vtoks, vok, self.cache = self._verify_fn(
-            self.params, jnp.asarray(toks), self.cache,
-            self._dev_tables_for(mask), jnp.asarray(lens, jnp.int32),
-            jnp.asarray(scale))
-        if plan is not None and any(
-                e.get("kernel") == "paged_verify"
-                for e in plan.log[log_n:]):
-            # A verify dispatch consumed an injected fault while tracing.
-            # The guarded dispatch already quarantined it and traced a
-            # correct fallback, so this step's outputs are still good —
-            # but the kernel is suspect: degrade to plain decode.
-            self._spec_disabled = True
-            self.spec_fallbacks += 1
-        outs = np.asarray(vtoks)                  # (B, K) greedy argmax
-        okh = np.asarray(vok).reshape(-1)
-        if plan is not None:
-            slow = plan.take_slowdown("paged_verify")
-            if slow > 0:
-                time.sleep(slow)   # inside the timing window: drift-visible
-        t = time.perf_counter()
-        if det is not None:
-            self._observe_drift(det, "paged_verify", t - t_disp)
-        committed = 0
-        for b in np.nonzero(mask)[0]:
-            b = int(b)
-            if not okh[b]:
-                continue
-            seq = sched.slots[b]
-            req = seq.req
-            # Longest accepted prefix: position t's output is committed
-            # while every draft before it matched the model's choice.
-            a = 0
-            while a < K - 1 and toks[b, a + 1] == outs[b, a]:
-                a += 1
-            take = min(a + 1, req.max_new_tokens - len(req.tokens))
-            req.tokens.extend(int(x) for x in outs[b, :take])
-            for _ in range(take):
-                self._note_token(req, t)
-            sched.commit_verify(b, take)
-            committed += take
-            self.spec_steps += 1
-        if not okh[mask].all():
-            # Non-finite verify logits: commit nothing for those slots,
-            # quarantine the verify config, and fall back to plain
-            # decode — the request survives and re-scores next step.
-            self._spec_disabled = True
-            self.spec_fallbacks += 1
-            stats.degraded += 1
-            self._requarantine_and_rejit("paged_verify")
-        self.spec_committed += committed
-        stats.decode_tokens = committed
+        with self._span("serve.verify.launch", slots=int(mask.sum())):
+            t_disp = time.perf_counter()
+            vtoks, vok, self.cache = self._verify_fn(
+                self.params, toks_d, self.cache, tables, lens_d, scale_d)
+            if plan is not None and any(
+                    e.get("kernel") == "paged_verify"
+                    for e in plan.log[log_n:]):
+                # A verify dispatch consumed an injected fault while
+                # tracing. The guarded dispatch already quarantined it
+                # and traced a correct fallback, so this step's outputs
+                # are still good — but the kernel is suspect: degrade to
+                # plain decode.
+                self._spec_disabled = True
+                self.spec_fallbacks += 1
+        with self._span("serve.verify.wait"):
+            outs = np.asarray(vtoks)              # (B, K) greedy argmax
+            okh = np.asarray(vok).reshape(-1)
+            if plan is not None:
+                slow = plan.take_slowdown("paged_verify")
+                if slow > 0:
+                    time.sleep(slow)   # inside the timing window: drift-visible
+            t = time.perf_counter()
+        with self._span("serve.verify.commit"):
+            if det is not None:
+                self._observe_drift(det, "paged_verify", t - t_disp)
+            committed = 0
+            for b in np.nonzero(mask)[0]:
+                b = int(b)
+                if not okh[b]:
+                    continue
+                seq = sched.slots[b]
+                req = seq.req
+                # Longest accepted prefix: position t's output is
+                # committed while every draft before it matched the
+                # model's choice.
+                a = 0
+                while a < K - 1 and toks[b, a + 1] == outs[b, a]:
+                    a += 1
+                take = min(a + 1, req.max_new_tokens - len(req.tokens))
+                req.tokens.extend(int(x) for x in outs[b, :take])
+                for _ in range(take):
+                    self._note_token(req, t)
+                sched.commit_verify(b, take)
+                committed += take
+                self.spec_steps += 1
+            if not okh[mask].all():
+                # Non-finite verify logits: commit nothing for those
+                # slots, quarantine the verify config, and fall back to
+                # plain decode — the request survives and re-scores next
+                # step.
+                self._spec_disabled = True
+                self.spec_fallbacks += 1
+                stats.degraded += 1
+                self._requarantine_and_rejit("paged_verify")
+            self.spec_committed += committed
+            stats.decode_tokens = committed
+
+    def _step_decode(self, plan, stats: StepStats) -> None:
+        """One plain decode step for every ready slot: one token each
+        through ``paged_decode``, a non-finite step failing its request
+        and re-jitting past the quarantined config."""
+        sched = self.scheduler
+        with self._span("serve.decode.prepare"):
+            mask = sched.decode_mask()
+            if not mask.any():
+                return
+            toks = np.zeros((sched.max_batch, 1), np.int32)
+            for b in np.nonzero(mask)[0]:
+                toks[b, 0] = sched.slots[int(b)].req.tokens[-1]
+            toks_d, tables, lens_d, scale_d = self._step_inputs(
+                mask, toks, plan)
+        det = self._drift_detector()
+        with self._span("serve.decode.launch", slots=int(mask.sum())):
+            t_disp = time.perf_counter()
+            dtoks, dok, self.cache = self._decode_fn(
+                self.params, toks_d, self.cache, tables, lens_d, scale_d)
+        with self._span("serve.decode.wait"):
+            next_tok = np.asarray(dtoks)
+            okh = np.asarray(dok).reshape(-1)
+            if plan is not None:
+                slow = plan.take_slowdown("paged_decode")
+                if slow > 0:
+                    time.sleep(slow)   # drift-visible injected latency
+            t = time.perf_counter()
+        with self._span("serve.decode.commit"):
+            if det is not None:
+                # The asarray above synced the step, so t - t_disp is the
+                # full dispatch-to-host latency of this launch.
+                self._observe_drift(det, "paged_decode", t - t_disp)
+            rejit = False
+            for b in np.nonzero(mask)[0]:
+                seq = sched.slots[int(b)]
+                if okh[b]:
+                    seq.req.tokens.append(int(next_tok[b]))
+                    self._note_token(seq.req, t)
+                else:
+                    # Garbage argmax tokens must never reach the caller:
+                    # fail the request and quarantine the decode config.
+                    sched.fail_slot(int(b), "non-finite decode logits")
+                    rejit = True
+            if rejit:
+                self._requarantine_and_rejit()
+            sched.advance_decoded(mask & okh)
+            stats.decode_tokens = int((mask & okh).sum())
+
+    def _step_prefill(self, chunk, stats: StepStats) -> None:
+        """Run this step's prompt chunk; the chunk that completes a fresh
+        prompt reads its last position back as the first token."""
+        jnp = self._jnp
+        sched = self.scheduler
+        b, tokens, start, valid = chunk
+        seq = sched.slots[b]
+        rid = int(seq.req.rid)
+        with self._span("serve.prefill.launch", rid=rid, slot=int(b),
+                        start=int(start), tokens=int(valid)):
+            table = jnp.asarray(sched.block_tables()[b:b + 1])
+            ptoks, pok, self.cache = self._prefill_fn(
+                self.params, jnp.asarray(tokens[None]), self.cache,
+                table, jnp.asarray([start], jnp.int32))
+            sched.mark_prefilled(b, valid)
+            stats.prefill_tokens = valid
+        if not seq.prompt_done or seq.req.tokens:
+            # Mid-prompt chunks are never read back. (A resumed sequence
+            # skips the read too: its next token is the last generated
+            # one, re-entering through decode.)
+            return
+        # First generated token comes straight from prefill argmax.
+        with self._span("serve.prefill.wait", rid=rid):
+            ok = bool(np.asarray(pok)[0, valid - 1])
+            tok = int(ptoks[0, valid - 1]) if ok else None
+        if ok:
+            seq.req.tokens.append(tok)
+            self._note_token(seq.req, time.perf_counter())
+        else:
+            sched.fail_slot(b, "non-finite prefill logits")
 
     def step(self, now: float = float("inf")) -> StepStats:
-        """One scheduler iteration; returns what happened."""
-        jnp = self._jnp
+        """One scheduler iteration; returns what happened.
+
+        Each host phase is a span (``_span``) inside one ``serve.step``
+        span, in order: ``serve.retire``, ``serve.admit`` (with the
+        choice of this step's prompt chunk), ``serve.prefill.launch``,
+        ``serve.prefill.wait`` (only when the chunk completes a prompt),
+        then ``serve.decode.{prepare,launch,wait,commit}`` or, when
+        speculating, ``serve.verify.*``. ``serve.step`` carries
+        ``perf_ns``, ``time.perf_counter_ns()`` at entry: the anchor that
+        maps the program's clock (``Request`` stamps, tracer timestamps)
+        onto the profiler's."""
+        self.step_num += 1
+        with self._span("serve.step", step_num=self.step_num,
+                        perf_ns=time.perf_counter_ns()):
+            return self._step(now)
+
+    def _step(self, now: float) -> StepStats:
         sched = self.scheduler
         plan = fault_lib.get_active()
         if self._drift_pending:
             self._poll_drift_retunes()
         stats = StepStats()
         pre = (sched.preemptions, sched.failures, sched.timeouts)
-        with self._span("retire"):
+        with self._span("serve.retire"):
             retired = sched.retire_finished()
+            for req in retired:
+                self._drafters.pop(req.rid, None)
         stats.retired = len(retired)
-        for req in retired:
-            self._drafters.pop(req.rid, None)
-        with self._span("admit"):
+        with self._span("serve.admit"):
             admitted = sched.admit(now)
-        stats.admitted = len(admitted)
-        stats.prefix_cached_tokens = sum(
-            sched.slots[b].cached_tokens for b in admitted)
-        if plan is not None:
-            plan.on_step(sched._step, self.pool)
-
-        chunk = sched.next_prefill()
+            stats.admitted = len(admitted)
+            stats.prefix_cached_tokens = sum(
+                sched.slots[b].cached_tokens for b in admitted)
+            if plan is not None:
+                plan.on_step(sched._step, self.pool)
+            chunk = sched.next_prefill()
         if chunk is not None:
-            b, tokens, start, valid = chunk
-            with self._span("prefill", slot=int(b), tokens=int(valid)):
-                table = jnp.asarray(sched.block_tables()[b:b + 1])
-                ptoks, pok, self.cache = self._prefill_fn(
-                    self.params, jnp.asarray(tokens[None]), self.cache,
-                    table, jnp.asarray([start], jnp.int32))
-                sched.mark_prefilled(b, valid)
-                stats.prefill_tokens = valid
-                seq = sched.slots[b]
-                if seq.prompt_done and not seq.req.tokens:
-                    # First generated token comes straight from prefill
-                    # argmax. (A resumed sequence skips this: its next
-                    # token is the last generated one, re-entering
-                    # through decode below.)
-                    if bool(np.asarray(pok)[0, valid - 1]):
-                        seq.req.tokens.append(int(ptoks[0, valid - 1]))
-                        self._note_token(seq.req, time.perf_counter())
-                    else:
-                        sched.fail_slot(b, "non-finite prefill logits")
-
-        speculate = self.spec_k > 1 and not self._spec_disabled
-        mask = sched.decode_mask(lookahead=self.spec_k if speculate else 1)
-        if mask.any() and speculate:
-            with self._span("verify", slots=int(mask.sum())):
-                self._step_verify(mask, plan, stats)
-        elif mask.any():
-            with self._span("decode", slots=int(mask.sum())):
-                toks = np.zeros((sched.max_batch, 1), np.int32)
-                for b in np.nonzero(mask)[0]:
-                    toks[b, 0] = sched.slots[int(b)].req.tokens[-1]
-                lens = sched.lens() * mask        # inactive slots -> 0
-                scale = np.ones((sched.max_batch, 1), np.float32)
-                if plan is not None:
-                    active = [int(b) for b in np.nonzero(mask)[0]]
-                    for s in plan.logit_poison(sched._step, active):
-                        scale[s] = float("nan")
-                det = self._drift_detector()
-                t_disp = time.perf_counter()
-                dtoks, dok, self.cache = self._decode_fn(
-                    self.params, jnp.asarray(toks), self.cache,
-                    self._dev_tables_for(mask),
-                    jnp.asarray(lens, jnp.int32), jnp.asarray(scale))
-                next_tok = np.asarray(dtoks)
-                okh = np.asarray(dok).reshape(-1)
-                if plan is not None:
-                    slow = plan.take_slowdown("paged_decode")
-                    if slow > 0:
-                        time.sleep(slow)   # drift-visible injected latency
-                t = time.perf_counter()
-                if det is not None:
-                    # The asarray above synced the step, so t - t_disp is
-                    # the full dispatch-to-host latency of this launch.
-                    self._observe_drift(det, "paged_decode", t - t_disp)
-                rejit = False
-                for b in np.nonzero(mask)[0]:
-                    seq = sched.slots[int(b)]
-                    if okh[b]:
-                        seq.req.tokens.append(int(next_tok[b]))
-                        self._note_token(seq.req, t)
-                    else:
-                        # Garbage argmax tokens must never reach the
-                        # caller: fail the request and quarantine the
-                        # decode config.
-                        sched.fail_slot(int(b), "non-finite decode logits")
-                        rejit = True
-                if rejit:
-                    self._requarantine_and_rejit()
-                sched.advance_decoded(mask & okh)
-                stats.decode_tokens = int((mask & okh).sum())
+            self._step_prefill(chunk, stats)
+        if self.spec_k > 1 and not self._spec_disabled:
+            self._step_verify(plan, stats)
+        else:
+            self._step_decode(plan, stats)
         stats.preempted = sched.preemptions - pre[0]
         stats.failed = sched.failures - pre[1]
         stats.timed_out = sched.timeouts - pre[2]
@@ -1340,7 +1440,6 @@ class ServingEngine:
                 self.scheduler.submit(req)
         plan = fault_lib.get_active()
         t0 = time.perf_counter()
-        self._run_t0 = t0
         steps = 0
         stalls = 0
         while self.scheduler.has_work():
@@ -1396,7 +1495,7 @@ class ServingEngine:
             "timed_out_requests": sum(
                 r.state is RequestState.TIMED_OUT for r in requests),
             "terminal_requests": sum(r.terminal() for r in requests),
-            "latency": latency_summary(requests, t0),
+            "latency": latency_summary(requests),
         }
         if self.spec_k > 1:
             out["speculative"] = {
