@@ -60,8 +60,9 @@ _TP_PARAM_AXES = frozenset({"heads", "kv_heads", "ff"})
 _CACHE_TP_AXIS = {
     # dense decode caches: k/v (B, slots, Hkv, D), scales (B, slots, Hkv)
     "k": -2, "v": -2, "k_scale": -1, "v_scale": -1,
-    # paged pools: pages (Hkv, P, page_size, D), scales (Hkv, P, page_size)
-    "k_pages": -4, "v_pages": -4, "k_scales": -3, "v_scales": -3,
+    # paged pools: pages (L, Hkv, P, page_size, D), scales
+    # (L, Hkv, P, 1, page_size)
+    "k_pages": -4, "v_pages": -4, "k_scales": -4, "v_scales": -4,
 }
 
 

@@ -812,16 +812,19 @@ PAGED_DECODE = TunableKernel(
 
 
 def paged_decode(q, k_pages, v_pages, block_tables, kv_len, *,
-                 k_scales=None, v_scales=None,
+                 layer=0, k_scales=None, v_scales=None,
                  scale: Optional[float] = None,
                  config: Optional[Config] = None,
                  tuner: Optional[Autotuner] = None,
                  interpret: Optional[bool] = None):
-    """Autotuned paged decode. q (B,Hq,D); k/v_pages (Hkv,P,page_size,D);
-    block_tables (B,max_pages) int32; kv_len (B,) int32. Int8 pools (the
-    kv8 policy) pass per-token ``k_scales``/``v_scales``
-    (Hkv,P,page_size) f32 — the context dtype becomes "int8", so int8 and
-    float pools tune (and cache) as distinct scenarios.
+    """Autotuned paged decode. q (B,Hq,D); k/v_pages (Hkv,P,page_size,D),
+    or the stacked pool of every layer (L,Hkv,P,page_size,D) read in place
+    at ``layer``; block_tables (B,max_pages) int32; kv_len (B,) int32.
+    Int8 pools (the kv8 policy) pass per-token ``k_scales``/``v_scales``
+    (Hkv,P,page_size) f32, or (L,Hkv,P,1,page_size) beside a stacked pool
+    — the context dtype becomes "int8", so int8 and float pools tune (and
+    cache) as distinct scenarios. The tuning context holds one layer's
+    dims, so a stacked pool tunes as its layers' pool does.
 
     The pool layout pins ``page_size``, so the runtime lookup context
     carries it in ``extra`` and only matching configs are explored; the
@@ -833,7 +836,7 @@ def paged_decode(q, k_pages, v_pages, block_tables, kv_len, *,
     through the runner-up portfolio down to the ``ref.py`` oracle.
     """
     from repro.kernels.paged_decode import paged_decode as paged_kernel
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[-2]
     guarded = config is None
     ctx = None
     _ps_values = next(p.values for p in PAGED_DECODE.space.params
@@ -846,7 +849,7 @@ def paged_decode(q, k_pages, v_pages, block_tables, kv_len, *,
     if config is None:
         tuner = tuner or default_tuner()
         B, Hq, D = q.shape
-        Hkv = k_pages.shape[0]
+        Hkv = k_pages.shape[-4]
         T = block_tables.shape[1] * ps
         ctx = _ctx(tuner, {"q": (B, Hq, D), "k": (B, Hkv, T, D)},
                    str(k_pages.dtype), page_size=ps)
@@ -858,14 +861,15 @@ def paged_decode(q, k_pages, v_pages, block_tables, kv_len, *,
         c = dict(cfg)
         c.pop("page_size", None)
         return paged_kernel(q, k_pages, v_pages, block_tables, kv_len,
-                            k_scales=k_scales, v_scales=v_scales,
-                            scale=scale, interpret=interpret, **c)
+                            layer=layer, k_scales=k_scales,
+                            v_scales=v_scales, scale=scale,
+                            interpret=interpret, **c)
 
     if guarded and _guard_active():
         def ref_run():
             from repro.kernels import ref
             return ref.paged_decode(q, k_pages, v_pages, block_tables,
-                                    kv_len, k_scales=k_scales,
+                                    kv_len, layer=layer, k_scales=k_scales,
                                     v_scales=v_scales, scale=scale)
         return _guarded_dispatch(PAGED_DECODE, ctx, config, run, ref_run,
                                  tuner)
@@ -1030,13 +1034,14 @@ PAGED_VERIFY = TunableKernel(
 
 
 def paged_verify(q, k_pages, v_pages, block_tables, kv_len, *,
-                 k_scales=None, v_scales=None,
+                 layer=0, k_scales=None, v_scales=None,
                  scale: Optional[float] = None,
                  config: Optional[Config] = None,
                  tuner: Optional[Autotuner] = None,
                  interpret: Optional[bool] = None):
     """Autotuned speculative verify. q (B,K,Hq,D) — K consecutive query
-    positions per sequence; k/v_pages (Hkv,P,page_size,D);
+    positions per sequence; k/v_pages (Hkv,P,page_size,D) or stacked
+    (L,Hkv,P,page_size,D) read at ``layer``, tuned by one layer's dims;
     block_tables (B,max_pages) int32; kv_len (B,) int32 valid tokens
     **including** the K scattered draft positions. Int8 pools (kv8) pass
     ``k_scales``/``v_scales`` as in ``paged_decode``.
@@ -1051,7 +1056,7 @@ def paged_verify(q, k_pages, v_pages, block_tables, kv_len, *,
     configs down to the ``src/repro/kernels/ref.py`` oracle.
     """
     from repro.kernels.paged_verify import paged_verify as verify_kernel
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[-2]
     B, K, Hq, D = q.shape
     guarded = config is None
     ctx = None
@@ -1066,7 +1071,7 @@ def paged_verify(q, k_pages, v_pages, block_tables, kv_len, *,
         tuner = None
     if config is None:
         tuner = tuner or default_tuner()
-        Hkv = k_pages.shape[0]
+        Hkv = k_pages.shape[-4]
         T = block_tables.shape[1] * ps
         ctx = _ctx(tuner, {"q": (B, Hq, D), "k": (B, Hkv, T, D)},
                    str(k_pages.dtype), page_size=ps, draft_k=K)
@@ -1079,14 +1084,15 @@ def paged_verify(q, k_pages, v_pages, block_tables, kv_len, *,
         c.pop("page_size", None)
         c.pop("draft_k", None)
         return verify_kernel(q, k_pages, v_pages, block_tables, kv_len,
-                             k_scales=k_scales, v_scales=v_scales,
-                             scale=scale, interpret=interpret, **c)
+                             layer=layer, k_scales=k_scales,
+                             v_scales=v_scales, scale=scale,
+                             interpret=interpret, **c)
 
     if guarded and _guard_active():
         def ref_run():
             from repro.kernels import ref
             return ref.paged_verify(q, k_pages, v_pages, block_tables,
-                                    kv_len, k_scales=k_scales,
+                                    kv_len, layer=layer, k_scales=k_scales,
                                     v_scales=v_scales, scale=scale)
         return _guarded_dispatch(PAGED_VERIFY, ctx, config, run, ref_run,
                                  tuner)

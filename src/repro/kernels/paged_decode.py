@@ -7,12 +7,16 @@ instead of the tokens actually resident. The serving subsystem
 pages; each sequence owns an ordered list of page ids (its *block table*)
 and appends tokens to its last partially-filled page.
 
-This kernel consumes that layout directly. The block table and the
-per-sequence valid lengths are **scalar-prefetched**
+This kernel consumes that layout directly. The block table, the
+per-sequence valid lengths and the layer index are **scalar-prefetched**
 (``pltpu.PrefetchScalarGridSpec``) so the KV BlockSpec index map can chase
-the table: grid step ``(row, j)`` DMAs physical page ``table[b, j]`` —
-a gather at page granularity with zero host-side reshuffling, the TPU
-analogue of vLLM's PagedAttention.
+the table: grid step ``(row, j)`` DMAs physical page ``table[b, j]`` of
+layer ``layer`` — a gather at page granularity with zero host-side
+reshuffling, the TPU analogue of vLLM's PagedAttention. The serving model
+keeps every layer's pool stacked in one buffer ``(L, Hkv, P, page_size,
+D)`` and passes it whole with the layer to read, so no layer's pool is
+ever sliced out of it; a single-layer pool ``(Hkv, P, page_size, D)``
+(the tuner's and the oracles' form) is read as a stack of one.
 
 Tunables (registered as ``paged_decode`` in the kernel registry):
 
@@ -53,7 +57,7 @@ NEG_INF = -1e30
 LANES = 128
 
 
-def _paged_kernel(tbl_ref, len_ref,                # scalar-prefetched
+def _paged_kernel(tbl_ref, len_ref, layer_ref,     # scalar-prefetched
                   q_ref, k_ref, v_ref,             # inputs (k/v: one page)
                   *rest,                           # [ks, vs,] o, scratch...
                   scale: float, page_size: int, pages_per_block: int,
@@ -117,11 +121,40 @@ def _paged_kernel(tbl_ref, len_ref,                # scalar-prefetched
     def _finalize():
         l = l_ref[:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)   # kv_len==0 row -> zeros
-        o_ref[0] = acc_ref[...] / safe_l
+        # Only q's head dim leaves the kernel: the pool's lanes past it
+        # hold zeros.
+        o_ref[0] = (acc_ref[...] / safe_l)[:, :o_ref.shape[-1]]
+
+
+def pool_head_dim(head_dim: int) -> int:
+    """The head dim a page pool stores: ``head_dim`` rounded up to whole
+    128-lane rows, zeros past it. The TPU's default layout of a pool whose
+    last dim is narrower than the lanes puts the page slots minor, which
+    the kernels cannot read in place; at whole lanes the default layout is
+    the row-major one they read. It costs no more memory than row-major
+    at the narrower width, which pads each row to the lanes anyway."""
+    return -(-head_dim // LANES) * LANES
+
+
+def stack_pools(k_pages, v_pages, k_scales, v_scales, layer):
+    """The kernels' operand form of a pool: stacked ``(L, Hkv, P,
+    page_size, D)`` pages with scales ``(L, Hkv, P, 1, page_size)``, and
+    the layer to read as a ``(1,)`` int32 array. A single-layer pool
+    ``(Hkv, P, page_size, D)`` with ``(Hkv, P, page_size)`` scales becomes
+    a stack of one read at layer 0."""
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if k_scales is not None:
+            k_scales, v_scales = (sc[None, :, :, None, :]
+                                  for sc in (k_scales, v_scales))
+        layer = 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    return k_pages, v_pages, k_scales, v_scales, layer
 
 
 def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                  block_tables: jnp.ndarray, kv_len: jnp.ndarray, *,
+                 layer=0,
                  k_scales: Optional[jnp.ndarray] = None,
                  v_scales: Optional[jnp.ndarray] = None,
                  scale: Optional[float] = None,
@@ -131,27 +164,36 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     """Block-table-indexed decode attention over a shared page pool.
 
     q            (B, Hq, D)   one query token per sequence
-    k_pages      (Hkv, P, page_size, D)   the pool (all sequences share it)
-    v_pages      (Hkv, P, page_size, D)
+    k_pages      (Hkv, P, page_size, D)   the pool (all sequences share it),
+    v_pages      or every layer's pool stacked, (L, Hkv, P, page_size, D),
+                 of which layer ``layer`` (an int32 scalar) is read in place;
+                 the pool's D may exceed q's (``pool_head_dim``: zeros past
+                 q's head dim)
     block_tables (B, max_pages) int32     logical block j of seq b -> page id
     kv_len       (B,) int32               valid tokens per sequence
-    k_scales     optional (Hkv, P, page_size) f32 — required iff the pools
-    v_scales     are int8 (the kv8 policy): per-token dequant scales,
-                 chased through the same block tables as the pages
+    k_scales     optional — required iff the pools are int8 (the kv8
+    v_scales     policy): per-token f32 dequant scales chased through the
+                 same block tables as the pages, (Hkv, P, page_size) beside
+                 a single-layer pool, (L, Hkv, P, 1, page_size) beside a
+                 stacked one
 
-    ``page_size`` is a property of the pool layout (``k_pages.shape[2]``);
+    ``page_size`` is a property of the pool layout (``k_pages.shape[-2]``);
     ``block_kv`` must be a multiple of it (default: one page per block).
     Rows with ``kv_len == 0`` (inactive batch slots) return zeros.
     """
-    B, Hq, D = q.shape
-    Hkv, n_pages, page_size, _ = k_pages.shape
-    assert Hq % Hkv == 0
+    B, Hq, head_dim = q.shape
     quantized = k_pages.dtype == jnp.int8
     assert quantized == (k_scales is not None) == (v_scales is not None), \
         "int8 pools require k_scales/v_scales; float pools forbid them"
+    k_pages, v_pages, k_scales, v_scales, layer = stack_pools(
+        k_pages, v_pages, k_scales, v_scales, layer)
+    _, Hkv, n_pages, page_size, D = k_pages.shape
+    assert Hq % Hkv == 0
     group = Hq // Hkv
     if scale is None:
-        scale = D ** -0.5
+        scale = head_dim ** -0.5
+    # Zero lanes of q against the pool's zero lanes add nothing.
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, D - head_dim)))
     if block_kv is None:
         block_kv = page_size
     assert block_kv % page_size == 0, (block_kv, page_size)
@@ -176,28 +218,31 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     def kv_head(r):
         return r % Hkv if pack_gqa else (r % Hq) // group
 
-    def kv_index(r, sj, pj, tbl, lens, ppb=pages_per_block):
-        return (kv_head(r), tbl[r // heads_per_b, sj * ppb + pj], 0, 0)
+    def kv_index(r, sj, pj, tbl, lens, lyr, ppb=pages_per_block):
+        return (lyr[0], kv_head(r), tbl[r // heads_per_b, sj * ppb + pj],
+                0, 0)
+
+    def row_index(r, sj, pj, tbl, lens, lyr):
+        return (r, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, g, D), lambda r, sj, pj, tbl, lens: (r, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, D), kv_index),
-        pl.BlockSpec((1, 1, page_size, D), kv_index),
+        pl.BlockSpec((1, g, D), row_index),
+        pl.BlockSpec((pl.squeezed, 1, 1, page_size, D), kv_index),
+        pl.BlockSpec((pl.squeezed, 1, 1, page_size, D), kv_index),
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
-        # Scale pools go in as (Hkv, P, 1, page_size): a (1, page_size)
-        # block spans whole trailing dims, which Mosaic requires of a
-        # block that is not (8, 128)-aligned.
-        in_specs += [pl.BlockSpec((1, 1, 1, page_size), kv_index)] * 2
-        operands += [sc.astype(jnp.float32)[:, :, None, :]
-                     for sc in (k_scales, v_scales)]
+        # Scale pools are laid out (L, Hkv, P, 1, page_size): a
+        # (1, page_size) block spans whole trailing dims, which Mosaic
+        # requires of a block that is not (8, 128)-aligned.
+        in_specs += [pl.BlockSpec((pl.squeezed, 1, 1, 1, page_size),
+                                  kv_index)] * 2
+        operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(rows, n_super, pages_per_block),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, g, D),
-                               lambda r, sj, pj, tbl, lens: (r, 0, 0)),
+        out_specs=pl.BlockSpec((1, g, head_dim), row_index),
         scratch_shapes=[
             pltpu.VMEM((g, D), jnp.float32),
             pltpu.VMEM((g, LANES), jnp.float32),
@@ -211,9 +256,9 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, g, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((rows, g, head_dim), jnp.float32),
         interpret=resolve_interpret(interpret),
         name="paged_decode",
-    )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
+    )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32), layer,
       *operands)
-    return o.reshape(B, Hq, D).astype(q.dtype)
+    return o.reshape(B, Hq, head_dim).astype(q.dtype)

@@ -46,12 +46,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.mode import resolve_interpret
+from repro.kernels.paged_decode import stack_pools
 
 NEG_INF = -1e30
 LANES = 128
 
 
-def _verify_kernel(tbl_ref, len_ref,               # scalar-prefetched
+def _verify_kernel(tbl_ref, len_ref, layer_ref,    # scalar-prefetched
                    q_ref, k_ref, v_ref,            # inputs (k/v: one page)
                    *rest,                          # [ks, vs,] o, scratch...
                    scale: float, page_size: int, pages_per_block: int,
@@ -117,11 +118,13 @@ def _verify_kernel(tbl_ref, len_ref,               # scalar-prefetched
     def _finalize():
         l = l_ref[:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)   # masked row -> zeros
-        o_ref[0] = acc_ref[...] / safe_l
+        # Only q's head dim leaves the kernel (see paged_decode).
+        o_ref[0] = (acc_ref[...] / safe_l)[:, :o_ref.shape[-1]]
 
 
 def paged_verify(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                  block_tables: jnp.ndarray, kv_len: jnp.ndarray, *,
+                 layer=0,
                  k_scales: Optional[jnp.ndarray] = None,
                  v_scales: Optional[jnp.ndarray] = None,
                  scale: Optional[float] = None,
@@ -131,27 +134,34 @@ def paged_verify(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     """Block-table-indexed K-position verify attention over a page pool.
 
     q            (B, K, Hq, D)  K consecutive query positions per sequence
-    k_pages      (Hkv, P, page_size, D)   the shared pool
-    v_pages      (Hkv, P, page_size, D)
+    k_pages      (Hkv, P, page_size, D)   the shared pool, or every
+    v_pages      layer's pool stacked (L, Hkv, P, page_size, D), read in
+                 place at layer ``layer``; D may exceed q's (see
+                 ``paged_decode``)
     block_tables (B, max_pages) int32
     kv_len       (B,) int32  valid tokens per sequence **including** the K
                  scattered draft positions: query t attends
                  ``k_pos <= kv_len - K + t``
-    k_scales     optional (Hkv, P, page_size) f32 per-token dequant scales
-    v_scales     — required iff the pools are int8 (the kv8 policy)
+    k_scales     optional f32 per-token dequant scales, (Hkv, P, page_size)
+    v_scales     or stacked (L, Hkv, P, 1, page_size) — required iff the
+                 pools are int8 (the kv8 policy)
 
     Rows with ``kv_len == 0`` (inactive slots) return zeros, as do query
     positions whose causal window is empty (``kv_len < K`` tails).
     """
-    B, K, Hq, D = q.shape
-    Hkv, n_pages, page_size, _ = k_pages.shape
-    assert Hq % Hkv == 0
+    B, K, Hq, head_dim = q.shape
     quantized = k_pages.dtype == jnp.int8
     assert quantized == (k_scales is not None) == (v_scales is not None), \
         "int8 pools require k_scales/v_scales; float pools forbid them"
+    k_pages, v_pages, k_scales, v_scales, layer = stack_pools(
+        k_pages, v_pages, k_scales, v_scales, layer)
+    _, Hkv, n_pages, page_size, D = k_pages.shape
+    assert Hq % Hkv == 0
     group = Hq // Hkv
     if scale is None:
-        scale = D ** -0.5
+        scale = head_dim ** -0.5
+    # Zero lanes of q against the pool's zero lanes add nothing.
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, D - head_dim)))
     if block_kv is None:
         block_kv = page_size
     assert block_kv % page_size == 0, (block_kv, page_size)
@@ -177,26 +187,29 @@ def paged_verify(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     def kv_head(r):
         return r % Hkv if pack_gqa else (r % Hq) // group
 
-    def kv_index(r, sj, pj, tbl, lens, ppb=pages_per_block):
-        return (kv_head(r), tbl[r // heads_per_b, sj * ppb + pj], 0, 0)
+    def kv_index(r, sj, pj, tbl, lens, lyr, ppb=pages_per_block):
+        return (lyr[0], kv_head(r), tbl[r // heads_per_b, sj * ppb + pj],
+                0, 0)
+
+    def row_index(r, sj, pj, tbl, lens, lyr):
+        return (r, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, K * g, D), lambda r, sj, pj, tbl, lens: (r, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, D), kv_index),
-        pl.BlockSpec((1, 1, page_size, D), kv_index),
+        pl.BlockSpec((1, K * g, D), row_index),
+        pl.BlockSpec((pl.squeezed, 1, 1, page_size, D), kv_index),
+        pl.BlockSpec((pl.squeezed, 1, 1, page_size, D), kv_index),
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
-        # (Hkv, P, 1, page_size) scale pools: see paged_decode.
-        in_specs += [pl.BlockSpec((1, 1, 1, page_size), kv_index)] * 2
-        operands += [sc.astype(jnp.float32)[:, :, None, :]
-                     for sc in (k_scales, v_scales)]
+        # (L, Hkv, P, 1, page_size) scale pools: see paged_decode.
+        in_specs += [pl.BlockSpec((pl.squeezed, 1, 1, 1, page_size),
+                                  kv_index)] * 2
+        operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(rows, n_super, pages_per_block),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, K * g, D),
-                               lambda r, sj, pj, tbl, lens: (r, 0, 0)),
+        out_specs=pl.BlockSpec((1, K * g, head_dim), row_index),
         scratch_shapes=[
             pltpu.VMEM((K * g, D), jnp.float32),
             pltpu.VMEM((K * g, LANES), jnp.float32),
@@ -211,11 +224,12 @@ def paged_verify(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, K * g, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((rows, K * g, head_dim), jnp.float32),
         interpret=resolve_interpret(interpret),
         name="paged_verify",
-    )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
+    )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32), layer,
       *operands)
+    D = head_dim
     o = o.reshape(rows, K, g, D)
     if pack_gqa:
         o = jnp.moveaxis(o.reshape(B, Hkv, K, g, D), 2, 1)

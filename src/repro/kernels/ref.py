@@ -91,8 +91,24 @@ def gather_pages(pages: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
     return dense.reshape(B, Hkv, n_blocks * page_size, D)
 
 
+def layer_pools(k_pages, v_pages, k_scales, v_scales, layer, head_dim):
+    """One layer's pool ``(Hkv, P, page_size, head_dim)`` and scales
+    ``(Hkv, P, page_size)``, from a single-layer pool or from every
+    layer's stacked pool ``(L, Hkv, P, page_size, D)`` with scales
+    ``(L, Hkv, P, 1, page_size)``; a pool's head dim may be padded past
+    ``head_dim`` (``paged_decode.pool_head_dim``)."""
+    if k_pages.ndim == 5:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
+        if k_scales is not None:
+            k_scales, v_scales = (sc[layer][:, :, 0] for sc in (k_scales,
+                                                              v_scales))
+    return (k_pages[..., :head_dim], v_pages[..., :head_dim], k_scales,
+            v_scales)
+
+
 def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                  block_tables: jnp.ndarray, kv_len: jnp.ndarray, *,
+                 layer=0,
                  k_scales: Optional[jnp.ndarray] = None,
                  v_scales: Optional[jnp.ndarray] = None,
                  scale: Optional[float] = None) -> jnp.ndarray:
@@ -100,7 +116,10 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     and run the dense ragged-decode reference. Rows with kv_len == 0
     (inactive batch slots) return zeros, matching the kernel. Int8 pools
     (the kv8 policy) pass per-token ``k_scales``/``v_scales``
-    (Hkv, P, page_size) and are dequantized before the gather."""
+    (Hkv, P, page_size) and are dequantized before the gather. A stacked
+    pool is read at ``layer`` (see ``layer_pools``)."""
+    k_pages, v_pages, k_scales, v_scales = layer_pools(
+        k_pages, v_pages, k_scales, v_scales, layer, q.shape[-1])
     if k_scales is not None:
         k_pages = k_pages.astype(jnp.float32) * \
             k_scales.astype(jnp.float32)[..., None]
@@ -116,6 +135,7 @@ def paged_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
 
 def paged_verify(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                  block_tables: jnp.ndarray, kv_len: jnp.ndarray, *,
+                 layer=0,
                  k_scales: Optional[jnp.ndarray] = None,
                  v_scales: Optional[jnp.ndarray] = None,
                  scale: Optional[float] = None) -> jnp.ndarray:
@@ -126,8 +146,10 @@ def paged_verify(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     scattered draft positions, so query t (absolute position
     ``kv_len - K + t``) attends ``k_pos <= kv_len - K + t``. Query rows
     with an empty causal window (inactive slots, ``kv_len < K`` tails)
-    return zeros, matching the kernel.
+    return zeros, matching the kernel. A stacked pool is read at ``layer``.
     """
+    k_pages, v_pages, k_scales, v_scales = layer_pools(
+        k_pages, v_pages, k_scales, v_scales, layer, q.shape[-1])
     if k_scales is not None:
         k_pages = k_pages.astype(jnp.float32) * \
             k_scales.astype(jnp.float32)[..., None]

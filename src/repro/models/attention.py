@@ -39,6 +39,10 @@ from repro.models.param import ParamSpec
 
 Cache = Dict[str, jnp.ndarray]
 
+# The page pool's reserved page (repro.serving.page_pool.SCRATCH_PAGE):
+# never owned by a sequence, so writes with nothing to write land there.
+SCRATCH_PAGE = 0
+
 
 # ===========================================================================
 # Core attention math (layout: q (B,S,Hq,Dq); k (B,T,Hkv,Dq); v (B,T,Hkv,Dv))
@@ -426,62 +430,140 @@ def attn_decode(p, x, cfg: ModelConfig, cache: Cache, pos, *, impl="full"):
 
 def paged_cache_spec(cfg: ModelConfig, num_pages: int, page_size: int,
                      kv_dtype: Optional[str] = None):
-    """ShapeDtypeStructs of this layer's shared page pool. Layout
+    """ShapeDtypeStructs of one layer's shared page pool. Layout
     (Hkv, P, page_size, D): the paged_decode kernel's block-table index map
-    picks (head, page) per grid step. ``kv_dtype="int8"`` (the kv8 policy)
-    makes the pools int8 and adds parallel per-token scale pools
-    (Hkv, P, page_size) the kernel chases through the same tables."""
+    picks (head, page) per grid step. D is the head dim rounded up to
+    whole lanes (``pool_head_dim``), so that the device's default layout
+    is the row-major one the kernels read in place. ``kv_dtype="int8"``
+    (the kv8 policy) makes the pools int8 and adds parallel per-token
+    scale pools (Hkv, P, 1, page_size) the kernel chases through the same
+    tables (a (1, page_size) row per page is the block Mosaic can fetch).
+    The model stacks every layer's pool of a unit along a leading axis
+    (``lm.paged_cache_specs``)."""
+    from repro.kernels.paged_decode import pool_head_dim
     dt = jnp.dtype(cfg.dtype)
-    shape = (cfg.n_kv_heads, num_pages, page_size, cfg.head_dim)
+    shape = (cfg.n_kv_heads, num_pages, page_size,
+             pool_head_dim(cfg.head_dim))
     if kv_dtype is None:
         return {"k_pages": jax.ShapeDtypeStruct(shape, dt),
                 "v_pages": jax.ShapeDtypeStruct(shape, dt)}
     assert kv_dtype == "int8", kv_dtype
     _check_kv8(cfg)
-    sshape = shape[:-1]
+    sshape = (cfg.n_kv_heads, num_pages, 1, page_size)
     return {"k_pages": jax.ShapeDtypeStruct(shape, jnp.int8),
             "v_pages": jax.ShapeDtypeStruct(shape, jnp.int8),
             "k_scales": jax.ShapeDtypeStruct(sshape, jnp.float32),
             "v_scales": jax.ShapeDtypeStruct(sshape, jnp.float32)}
 
 
-def _scatter_pages(pages, vals, block_tables, start):
-    """Write vals (B, S, Hkv, D) at token positions start[b] + s into the
-    pool (Hkv, P, page_size, D) through each sequence's block table
-    (B, max_pages). Inactive writes must be routed to the reserved scratch
-    page by the caller (table entry 0). Also scatters per-token scale
-    values — (B, S, Hkv) into (Hkv, P, page_size) — through the identical
-    index arithmetic (the trailing D axis just isn't there)."""
+def _write_pages(pool, vals, block_tables, start, layer):
+    """Write vals (B, S, Hkv, D) at token positions start[b] + s into layer
+    ``layer`` of the stacked pool (L, Hkv, P, page_size, D'), whose head
+    dim D' >= D is zero past D (``pool_head_dim``), through each
+    sequence's block table (B, max_pages). Only ``dynamic_update_slice``
+    writes the pool, in place: nothing the size of a pool is read, copied
+    or relaid out. Per-token scales — (B, S, Hkv) into a scale pool
+    (L, Hkv, P, 1, page_size) — take the same path with the slot axis
+    last.
+
+    A sequence's first page takes its n0 = min(S, page_size - slot)
+    tokens from the start slot on, one write per binary digit of n0:
+    fixed sizes that together cover exactly those slots, so the slots
+    before the start keep their tokens. Each later page the chunk reaches
+    takes one whole window from slot 0; its slots past the last token get
+    zeros, harmless because they lie past the sequence's length (a later
+    write fills them before attention reads them). A write with nothing
+    to write (a zero digit, a page past the chunk or past the table) goes
+    to the reserved scratch page, as must the caller's inactive rows
+    (table entry 0)."""
+    is_scale = vals.ndim == 3
     B, S = vals.shape[:2]
-    page_size = pages.shape[2]
-    pos = start[:, None] + jnp.arange(S)[None, :]              # (B, S)
-    blocks = jnp.clip(pos // page_size, 0, block_tables.shape[1] - 1)
-    page_ids = jnp.take_along_axis(block_tables, blocks, axis=1)
-    slots = pos % page_size
-    # (Hkv, B, S, D) values scattered at [:, page_ids, slots]
-    return pages.at[:, page_ids, slots].set(jnp.moveaxis(vals, 2, 0))
+    page_size = pool.shape[-1] if is_scale else pool.shape[-2]
+    slot_axis = 4 if is_scale else 3
+    W = min(S, page_size)
+    n_seg = (S + page_size - 2) // page_size + 1   # pages S tokens can touch
+    max_block = block_tables.shape[1] - 1
+    rows = jnp.moveaxis(vals, 2, 1).astype(pool.dtype)  # (B, Hkv, S[, D])
+    pad = [(0, 0), (0, 0), (0, W)]              # W more rows past S
+    if not is_scale:
+        pad.append((0, pool.shape[-1] - rows.shape[-1]))
+    rows = jnp.pad(rows, pad)
+
+    def write(pool, b, first_token, size, page, slot):
+        win = jax.lax.dynamic_slice_in_dim(rows[b], first_token, size, axis=1)
+        shape = [1, win.shape[0], 1, 1, 1]
+        shape[slot_axis] = size
+        if not is_scale:
+            shape[-1] = win.shape[-1]
+        at = [layer, 0, page, 0, 0]
+        at[slot_axis] = slot
+        return jax.lax.dynamic_update_slice(pool, win.reshape(shape), at)
+
+    for b in range(B):
+        first, slot = start[b] // page_size, start[b] % page_size
+        page = block_tables[b, jnp.minimum(first, max_block)]
+        n0 = jnp.minimum(S, page_size - slot)
+        off = 0
+        for k in reversed(range(W.bit_length())):
+            on = (n0 >> k) & 1
+            pool = write(pool, b, off, 1 << k,
+                         jnp.where(on == 1, page, SCRATCH_PAGE), slot + off)
+            off = off + on * (1 << k)
+        for i in range(1, n_seg):
+            t0 = i * page_size - slot   # the chunk's first token on page i
+            live = (t0 < S) & (first + i <= max_block)
+            page = block_tables[b, jnp.minimum(first + i, max_block)]
+            pool = write(pool, b, t0, W,
+                         jnp.where(live, page, SCRATCH_PAGE), 0)
+    return pool
 
 
-def _gather_pages_bthd(pages, block_tables):
-    """Densify the pool for the prefill path: (B, capacity, Hkv, D)."""
-    from repro.kernels.ref import gather_pages
-    return jnp.moveaxis(gather_pages(pages, block_tables), 1, 2)
+def _gather_pages_bthd(pages, block_tables, layer, head_dim):
+    """Densify layer ``layer`` of the stacked pool for the prefill path,
+    reading only the tables' pages in place: (B, capacity, Hkv,
+    head_dim)."""
+    from repro.kernels.paged_gather import paged_gather
+    dense = paged_gather(pages, block_tables, layer)   # (B, Hkv, nb, ps, D)
+    B, Hkv, nb, ps, D = dense.shape
+    dense = dense.reshape(B, Hkv, nb * ps, D)[..., :head_dim]
+    return jnp.moveaxis(dense, 1, 2)
 
 
-def _gather_scales_bth(scales, block_tables):
-    """Densify a per-token scale pool (Hkv, P, page_size) through the
-    block tables into (B, capacity, Hkv) — the scale-side twin of
-    ``_gather_pages_bthd``."""
-    Hkv, _, ps = scales.shape
-    B, nb = block_tables.shape
-    dense = scales[:, block_tables].reshape(Hkv, B, nb * ps)
-    return jnp.moveaxis(dense, 0, 2)
+def _gather_scales_bth(scales, block_tables, layer):
+    """Densify layer ``layer`` of a stacked per-token scale pool
+    (L, Hkv, P, 1, page_size) through the block tables into
+    (B, capacity, Hkv) — the scale-side twin of ``_gather_pages_bthd``."""
+    from repro.kernels.paged_gather import paged_gather
+    dense = paged_gather(scales, block_tables, layer)  # (B, Hkv, nb, 1, ps)
+    B, Hkv, nb, _, ps = dense.shape
+    return jnp.moveaxis(dense.reshape(B, Hkv, nb * ps), 1, 2)
 
 
-def attn_prefill_paged(p, x, cfg: ModelConfig, cache, block_tables, start):
-    """One chunked-prefill step: write the chunk's KV into the pool, then
-    attend the chunk's queries over the sequence's dense prefix (gathered
-    through the block table) — q_offset=start, causal.
+def _write_kv(cache, k, v, block_tables, start, layer):
+    """Quantize the new tokens' K/V when the pools are int8 (the kv8
+    policy), write them into layer ``layer`` of the stacked pools, and
+    return the new cache and the scale operands of the kernels."""
+    new = dict(cache)
+    scales = {}
+    if "k_scales" in cache:                 # int8 pools (kv8 policy)
+        k, ks, v, vs = _quant_kv_token(k, v)
+        for name, val in (("k_scales", ks), ("v_scales", vs)):
+            new[name] = _write_pages(cache[name], val, block_tables, start,
+                                     layer)
+        scales = {"k_scales": new["k_scales"], "v_scales": new["v_scales"]}
+    new["k_pages"] = _write_pages(cache["k_pages"], k, block_tables, start,
+                                  layer)
+    new["v_pages"] = _write_pages(cache["v_pages"], v, block_tables, start,
+                                  layer)
+    return new, scales
+
+
+def attn_prefill_paged(p, x, cfg: ModelConfig, cache, block_tables, start,
+                       layer):
+    """One chunked-prefill step: write the chunk's KV into layer ``layer``
+    of the stacked pool, then attend the chunk's queries over the
+    sequence's dense prefix (gathered through the block table) —
+    q_offset=start, causal.
 
     x (B, S, d); block_tables (B, max_pages) int32; start (B,) int32 —
     tokens already resident per sequence (the chunk occupies
@@ -492,25 +574,18 @@ def attn_prefill_paged(p, x, cfg: ModelConfig, cache, block_tables, start):
     B, S, _ = x.shape
     positions = start[:, None] + jnp.arange(S)[None, :]
     q, k, v = _qkv(p, x, cfg, positions)
-    new = dict(cache)
-    if "k_scales" in cache:                 # int8 pools (kv8 policy)
-        k, ks, v, vs = _quant_kv_token(k, v)
-        new["k_scales"] = _scatter_pages(cache["k_scales"], ks,
-                                         block_tables, start)
-        new["v_scales"] = _scatter_pages(cache["v_scales"], vs,
-                                         block_tables, start)
-    kp = _scatter_pages(cache["k_pages"], k, block_tables, start)
-    vp = _scatter_pages(cache["v_pages"], v, block_tables, start)
-    new["k_pages"], new["v_pages"] = kp, vp
-    kd = _gather_pages_bthd(kp, block_tables)
-    vd = _gather_pages_bthd(vp, block_tables)
-    if "k_scales" in cache:
+    new, scales = _write_kv(cache, k, v, block_tables, start, layer)
+    kd = _gather_pages_bthd(new["k_pages"], block_tables, layer,
+                            cfg.head_dim)
+    vd = _gather_pages_bthd(new["v_pages"], block_tables, layer,
+                            cfg.head_dim)
+    if scales:
         # Dequantize AFTER the gather: scales ride the same block tables,
         # and only the pages the active sequences own get the f32 copy
         # (dequantizing the whole pool would transiently materialize a
         # 4×-pool-sized buffer — the memory the int8 pool exists to save).
-        ksd = _gather_scales_bth(new["k_scales"], block_tables)
-        vsd = _gather_scales_bth(new["v_scales"], block_tables)
+        ksd = _gather_scales_bth(scales["k_scales"], block_tables, layer)
+        vsd = _gather_scales_bth(scales["v_scales"], block_tables, layer)
         kd = kd.astype(jnp.float32) * ksd[..., None]
         vd = vd.astype(jnp.float32) * vsd[..., None]
     # Per-sequence q_offset differs: mask via kv_valid/causal per batch row.
@@ -529,10 +604,13 @@ def attn_prefill_paged(p, x, cfg: ModelConfig, cache, block_tables, start):
     return _proj_out(p, o, cfg), new
 
 
-def attn_decode_paged(p, x, cfg: ModelConfig, cache, block_tables, lens):
+def attn_decode_paged(p, x, cfg: ModelConfig, cache, block_tables, lens,
+                      layer):
     """One-token paged decode. x (B, 1, d); lens (B,) int32 tokens already
     resident (the new token lands at position lens[b]; rows with the
-    scratch-only table and lens==0 are inactive padding slots).
+    scratch-only table and lens==0 are inactive padding slots). The token
+    is written into, and attention reads, layer ``layer`` of the stacked
+    pool in place.
 
     Dispatches the autotuned ``paged_decode`` registry kernel over the
     block tables — the serving hot path this subsystem exists for.
@@ -542,30 +620,22 @@ def attn_decode_paged(p, x, cfg: ModelConfig, cache, block_tables, lens):
     from repro.kernels import ops as kops
     positions = lens[:, None]                                  # (B, 1)
     q, k, v = _qkv(p, x, cfg, positions)
-    new = dict(cache)
-    scales = {}
-    if "k_scales" in cache:                 # int8 pools (kv8 policy)
-        k, ks, v, vs = _quant_kv_token(k, v)
-        new["k_scales"] = _scatter_pages(cache["k_scales"], ks,
-                                         block_tables, lens)
-        new["v_scales"] = _scatter_pages(cache["v_scales"], vs,
-                                         block_tables, lens)
-        scales = {"k_scales": new["k_scales"], "v_scales": new["v_scales"]}
-    kp = _scatter_pages(cache["k_pages"], k, block_tables, lens)
-    vp = _scatter_pages(cache["v_pages"], v, block_tables, lens)
-    new["k_pages"], new["v_pages"] = kp, vp
-    o = kops.paged_decode(q[:, 0], kp, vp, block_tables, lens + 1, **scales)
+    new, scales = _write_kv(cache, k, v, block_tables, lens, layer)
+    o = kops.paged_decode(q[:, 0], new["k_pages"], new["v_pages"],
+                          block_tables, lens + 1, layer=layer, **scales)
     return _proj_out(p, o[:, None], cfg), new
 
 
-def attn_verify_paged(p, x, cfg: ModelConfig, cache, block_tables, lens):
+def attn_verify_paged(p, x, cfg: ModelConfig, cache, block_tables, lens,
+                      layer):
     """Speculative verify: score K consecutive positions in one pass.
 
     x (B, K, d) — the last committed token plus K-1 drafted continuations;
     lens (B,) int32 tokens already resident (the K inputs land at
-    positions [lens, lens+K)). Writes all K positions' KV into the pool —
-    rejected drafts leave stale entries past the accepted prefix, which is
-    harmless: the scheduler rewinds ``pos`` and later scatters overwrite.
+    positions [lens, lens+K)). Writes all K positions' KV into layer
+    ``layer`` of the stacked pool — rejected drafts leave stale entries
+    past the accepted prefix, which is harmless: the scheduler rewinds
+    ``pos`` and later writes overwrite.
 
     Dispatches the autotuned ``paged_verify`` registry kernel: query t
     attends the resident prefix plus drafts 0..t (kv_len = lens + K with
@@ -578,19 +648,9 @@ def attn_verify_paged(p, x, cfg: ModelConfig, cache, block_tables, lens):
     B, K, _ = x.shape
     positions = lens[:, None] + jnp.arange(K)[None, :]          # (B, K)
     q, k, v = _qkv(p, x, cfg, positions)
-    new = dict(cache)
-    scales = {}
-    if "k_scales" in cache:                 # int8 pools (kv8 policy)
-        k, ks, v, vs = _quant_kv_token(k, v)
-        new["k_scales"] = _scatter_pages(cache["k_scales"], ks,
-                                         block_tables, lens)
-        new["v_scales"] = _scatter_pages(cache["v_scales"], vs,
-                                         block_tables, lens)
-        scales = {"k_scales": new["k_scales"], "v_scales": new["v_scales"]}
-    kp = _scatter_pages(cache["k_pages"], k, block_tables, lens)
-    vp = _scatter_pages(cache["v_pages"], v, block_tables, lens)
-    new["k_pages"], new["v_pages"] = kp, vp
-    o = kops.paged_verify(q, kp, vp, block_tables, lens + K, **scales)
+    new, scales = _write_kv(cache, k, v, block_tables, lens, layer)
+    o = kops.paged_verify(q, new["k_pages"], new["v_pages"], block_tables,
+                          lens + K, layer=layer, **scales)
     return _proj_out(p, o, cfg), new
 
 

@@ -443,44 +443,50 @@ def _apply_ffn(p, h, ffn, cfg: ModelConfig, opts: ForwardOpts):
 
 
 _PAGED_ATTN = {
-    "prefill": lambda *a: ATT.attn_prefill_paged(*a),
-    "decode": lambda *a: ATT.attn_decode_paged(*a),
-    "verify": lambda *a: ATT.attn_verify_paged(*a),
+    "prefill": ATT.attn_prefill_paged,
+    "decode": ATT.attn_decode_paged,
+    "verify": ATT.attn_verify_paged,
 }
 
 
-def _block_paged(p, h, kind, cfg, opts, cache, tables, start, *, mode):
+def _block_paged(p, h, kind, cfg, opts, cache, tables, start, layer, *,
+                 mode):
     mixer, ffn = kind.split("_")
     assert mixer == "attn", f"paged serving: unsupported mixer {mixer!r}"
     hn = apply_norm(p["ln1"], h, cfg, impl=opts.norm_impl)
     mix, c = _PAGED_ATTN[mode](p["mix"], hn, cfg, cache["self"],
-                               tables, start)
+                               tables, start, layer)
     h = _apply_ffn(p, h + mix, ffn, cfg, opts)
     return h, {"self": c}
 
 
 def _run_units_paged(params, h, cfg, opts, cache, tables, start, *, mode):
+    """Every unit's pools (stacked along the unit's repeats) ride in the
+    layer scan's carry with the activations; the layer index comes in as
+    ``xs`` beside the layer's weights. Each layer writes its new tokens and
+    reads its pages in place at that index, so no layer's pool is sliced
+    out of the stack or written back whole."""
     new_cache = {}
     for ui, (unit, reps) in enumerate(cfg.scan_plan()):
         pu = params[f"u{ui}"]
-        cu = cache[f"u{ui}"]
 
-        def body(h_, xs, unit=unit):
-            pl, cl = xs
-            hh = h_
-            ncs = {}
+        def body(carry, xs, unit=unit):
+            hh, cu = carry
+            pl, layer = xs
+            cu = dict(cu)
             for i, kind in enumerate(unit):
-                hh, nc = _block_paged(pl[f"l{i}"], hh, kind, cfg, opts,
-                                      cl[f"l{i}"], tables, start,
-                                      mode=mode)
-                ncs[f"l{i}"] = nc
-            return hh, ncs
+                hh, cu[f"l{i}"] = _block_paged(
+                    pl[f"l{i}"], hh, kind, cfg, opts, cu[f"l{i}"], tables,
+                    start, layer, mode=mode)
+            return (hh, cu), None
 
+        carry = (h, cache[f"u{ui}"])
         if reps == 1:
-            h, ncs = body(h, (pu, cu))
+            carry, _ = body(carry, (pu, 0))
         else:
-            h, ncs = jax.lax.scan(body, h, (pu, cu))
-        new_cache[f"u{ui}"] = ncs
+            carry, _ = jax.lax.scan(body, carry,
+                                    (pu, jnp.arange(reps, dtype=jnp.int32)))
+        h, new_cache[f"u{ui}"] = carry
     return h, new_cache
 
 
@@ -534,8 +540,10 @@ def verify_step_paged(params, cfg: ModelConfig, tokens, cache, block_tables,
 
 def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int,
                       kv_dtype: Optional[str] = None):
-    """ShapeDtypeStruct tree matching the paged cache (pool per layer).
-    ``kv_dtype="int8"`` (the kv8 policy) makes the pools int8 with
+    """ShapeDtypeStruct tree matching the paged cache: per unit and layer
+    of the unit, the pools of its ``reps`` repeats stacked in one array
+    (reps, Hkv, P, page_size, D) — a unit that does not repeat stacks
+    one. ``kv_dtype="int8"`` (the kv8 policy) makes the pools int8 with
     parallel per-token scale pools."""
     _check_paged(cfg)
     caches = {}
@@ -544,10 +552,8 @@ def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int,
                                                      page_size,
                                                      kv_dtype=kv_dtype)}
               for i, kind in enumerate(unit)}
-        if reps > 1:
-            cs = jax.tree.map(
-                lambda s: jax.ShapeDtypeStruct((reps,) + s.shape, s.dtype), cs)
-        caches[f"u{ui}"] = cs
+        caches[f"u{ui}"] = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct((reps,) + s.shape, s.dtype), cs)
     return caches
 
 

@@ -1,16 +1,17 @@
 """Block-pool KV allocator: fixed-size pages, ref-counted free list.
 
 The pool is pure host-side bookkeeping — the actual KV bytes live in the
-per-layer ``k_pages``/``v_pages`` device arrays (``lm.init_paged_cache``);
-every layer shares ONE logical block table per sequence, so allocation is
-done once per sequence here and reused across all layers.
+``k_pages``/``v_pages`` device arrays, every layer of a scanned unit
+stacked in one (``lm.init_paged_cache``); every layer shares ONE logical
+block table per sequence, so allocation is done once per sequence here
+and reused across all layers.
 
 Reference counting exists so pages can be *shared* between sequences
 (prefix caching / beam forks): ``share()`` bumps the count, ``free()``
 only returns a page to the free list when its last owner releases it.
 Page 0 is reserved as the scratch page: inactive batch slots and padded
-block-table entries point at it, so scatter/gather index maps always hit
-resident memory without branching.
+block-table entries point at it, so the device's writes and index maps
+always hit resident memory without branching.
 """
 
 from __future__ import annotations

@@ -702,10 +702,10 @@ class Scheduler:
         while it is occupied. The engine caches device block tables
         keyed on (rid, ready, len(pages)); a free-then-regrow can hand
         the page to another slot while the stale device table still
-        maps it here, so the next scatter would corrupt that slot's KV.
+        maps it here, so the next write would corrupt that slot's KV.
         The reservation is already charged by ``max_tokens``'s burst
         bound; preemption and retirement release it like any other
-        page. Stale draft KV past ``pos`` is harmless: the next scatter
+        page. Stale draft KV past ``pos`` is harmless: the next write
         overwrites it, attention never reads past ``kv_len``, and
         ``_park`` only parks full pages below ``pos``."""
         seq = self.slots[b]
@@ -794,6 +794,63 @@ class _Both:
     def __exit__(self, *exc):
         self.span.__exit__(*exc)
         return self.ann.__exit__(*exc)
+
+
+def step_programs(cfg, opts, *, mesh=None, verify: bool = False):
+    """The engine's prefill, decode and verify step programs, unjitted
+    (verify is None unless ``verify``). ``mesh`` serves tensor-parallel
+    through ``distribution/tp.py``'s shard_map wrappers.
+
+    Greedy sampling runs inside each step so only token ids (plus one
+    finite-logits bit per slot — the non-finite guard) cross the device
+    boundary every iteration, never logits."""
+    import jax.numpy as jnp
+
+    from repro.models import lm
+    if mesh is not None:
+        from repro.distribution import tp as tp_lib
+        step_prefill = tp_lib.make_tp_prefill_paged(cfg, mesh, opts=opts)
+        step_decode = tp_lib.make_tp_decode_paged(cfg, mesh, opts=opts)
+        step_verify = (tp_lib.make_tp_verify_paged(cfg, mesh, opts=opts)
+                       if verify else None)
+    else:
+        def step_prefill(params, tokens, cache, tables, start):
+            return lm.prefill_paged(params, cfg, tokens, cache, tables,
+                                    start, opts)
+
+        def step_decode(params, token, cache, tables, lens):
+            return lm.decode_step_paged(params, cfg, token, cache, tables,
+                                        lens, opts)
+
+        def step_verify(params, tokens, cache, tables, lens):
+            return lm.verify_step_paged(params, cfg, tokens, cache, tables,
+                                        lens, opts)
+
+    def _prefill(params, tokens, cache, tables, start):
+        logits, cache = step_prefill(params, tokens, cache, tables, start)
+        ok = jnp.isfinite(logits).all(-1)
+        return jnp.argmax(logits, -1).astype(jnp.int32), ok, cache
+
+    # ``scale`` is the fault harness's jit-compatible poison operand:
+    # all-ones normally, NaN rows inject non-finite logits at chosen
+    # steps without retracing.
+    def _decode(params, token, cache, tables, lens, scale):
+        logits, cache = step_decode(params, token, cache, tables, lens)
+        logits = logits * scale
+        ok = jnp.isfinite(logits).all(-1)
+        return jnp.argmax(logits, -1).astype(jnp.int32), ok, cache
+
+    # Verify: greedy argmax at each of the K draft positions; one
+    # finite bit per slot covers all K (any non-finite position
+    # invalidates the whole burst). ``scale`` is the same (B, 1)
+    # poison operand, broadcast over K.
+    def _verify(params, tokens, cache, tables, lens, scale):
+        logits, cache = step_verify(params, tokens, cache, tables, lens)
+        logits = logits * scale[:, :, None]
+        ok = jnp.isfinite(logits).all(-1).all(-1)
+        return jnp.argmax(logits, -1).astype(jnp.int32), ok, cache
+
+    return _prefill, _decode, (_verify if verify else None)
 
 
 class ServingEngine:
@@ -914,59 +971,12 @@ class ServingEngine:
             self.cache = tp_lib.init_cache(
                 lm.paged_cache_specs(cfg, num_pages, page_size,
                                      kv_dtype=kv_dtype), self.mesh)
-            step_prefill = tp_lib.make_tp_prefill_paged(cfg, self.mesh,
-                                                        opts=self.opts)
-            step_decode = tp_lib.make_tp_decode_paged(cfg, self.mesh,
-                                                      opts=self.opts)
-            step_verify = (tp_lib.make_tp_verify_paged(cfg, self.mesh,
-                                                       opts=self.opts)
-                           if self.spec_k > 1 else None)
         else:
             self.cache = lm.init_paged_cache(cfg, num_pages, page_size,
                                              kv_dtype=kv_dtype)
-
-            def step_prefill(params, tokens, cache, tables, start):
-                return lm.prefill_paged(params, cfg, tokens, cache,
-                                        tables, start, self.opts)
-
-            def step_decode(params, token, cache, tables, lens):
-                return lm.decode_step_paged(params, cfg, token, cache,
-                                            tables, lens, self.opts)
-
-            def step_verify(params, tokens, cache, tables, lens):
-                return lm.verify_step_paged(params, cfg, tokens, cache,
-                                            tables, lens, self.opts)
-
-        # Greedy sampling runs inside the jitted step so only token ids
-        # (plus one finite-logits bit per slot — the non-finite guard)
-        # cross the device boundary every iteration, never logits.
-        def _prefill(params, tokens, cache, tables, start):
-            logits, cache = step_prefill(params, tokens, cache, tables, start)
-            ok = jnp.isfinite(logits).all(-1)
-            return jnp.argmax(logits, -1).astype(jnp.int32), ok, cache
-
-        # ``scale`` is the fault harness's jit-compatible poison operand:
-        # all-ones normally, NaN rows inject non-finite logits at chosen
-        # steps without retracing.
-        def _decode(params, token, cache, tables, lens, scale):
-            logits, cache = step_decode(params, token, cache, tables, lens)
-            logits = logits * scale
-            ok = jnp.isfinite(logits).all(-1)
-            return jnp.argmax(logits, -1).astype(jnp.int32), ok, cache
-
-        # Verify: greedy argmax at each of the K draft positions; one
-        # finite bit per slot covers all K (any non-finite position
-        # invalidates the whole burst). ``scale`` is the same (B, 1)
-        # poison operand, broadcast over K.
-        def _verify(params, tokens, cache, tables, lens, scale):
-            logits, cache = step_verify(params, tokens, cache, tables, lens)
-            logits = logits * scale[:, :, None]
-            ok = jnp.isfinite(logits).all(-1).all(-1)
-            return jnp.argmax(logits, -1).astype(jnp.int32), ok, cache
-
-        self._prefill_raw = _prefill
-        self._decode_raw = _decode
-        self._verify_raw = _verify if self.spec_k > 1 else None
+        self._prefill_raw, self._decode_raw, self._verify_raw = \
+            step_programs(cfg, self.opts, mesh=self.mesh,
+                          verify=self.spec_k > 1)
         # Donate the cache on real accelerators: the previous pool buffers
         # are dead after every step, so donation avoids a full-pool copy
         # per token and 2x peak KV memory. On the CPU interpret-mode host
@@ -1183,7 +1193,7 @@ class ServingEngine:
              0 if s is None else len(s.pages))
             for s, m in zip(sched.slots, mask))
         if self._dev_tables is None or key != self._dev_tables_key:
-            # Inactive rows (idle or mid-prefill) must scatter their
+            # Inactive rows (idle or mid-prefill) must write their
             # dummy token into the scratch page, not through their
             # real tables.
             tables = sched.block_tables()

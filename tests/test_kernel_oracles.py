@@ -107,3 +107,103 @@ def test_quant_family_is_fully_swept_at_int8_cases():
     # must both conform.
     assert ("paged_decode", "int8") in swept
     assert ("paged_decode", "float32") in swept
+
+
+# ---------------------------------------------------------------------------
+# Stacked pools: the serving model hands the paged kernels every layer's
+# pool in one buffer (L, Hkv, P, page_size, D) and the layer to read.
+# ---------------------------------------------------------------------------
+
+def _stacked_pool(quantized, L=3, Hkv=2, P=9, ps=8, D=16):
+    """A stacked pool as the model stores it: head dim zero-padded to
+    whole lanes, scales (L, Hkv, P, 1, page_size)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.paged_decode import pool_head_dim
+    from repro.quant.calibrate import quantize_kv
+    kk, kv = jax.random.split(jax.random.PRNGKey(3))
+    k = jax.random.normal(kk, (L, Hkv, P, ps, D), jnp.float32)
+    v = jax.random.normal(kv, (L, Hkv, P, ps, D), jnp.float32)
+    scales = {}
+    if quantized:
+        k, ks, v, vs = quantize_kv(k, v)
+        scales = {"k_scales": ks[:, :, :, None, :],
+                  "v_scales": vs[:, :, :, None, :]}
+    lanes = [(0, 0)] * 4 + [(0, pool_head_dim(D) - D)]
+    return jnp.pad(k, lanes), jnp.pad(v, lanes), scales
+
+
+def _layer_of(pool_k, pool_v, scales, layer, head_dim):
+    return pool_k[layer, ..., :head_dim], pool_v[layer, ..., :head_dim], {
+        name: sc[layer][:, :, 0] for name, sc in scales.items()}
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("pack_gqa", [True, False], ids=["packed", "rows"])
+@pytest.mark.parametrize("kernel", ["paged_decode", "paged_verify"])
+def test_stacked_pool_reads_layer_in_place(kernel, pack_gqa, quantized):
+    """A stacked, lane-padded pool read at layer l gives the 4-D call on
+    pool[l], with and without int8 scales, for both head packings. The
+    zero lanes add only zeros, which may reorder a float32 sum: equal to
+    float32 rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.paged_decode import paged_decode
+    from repro.kernels.paged_verify import paged_verify
+    kp, vp, scales = _stacked_pool(quantized)
+    B, Hq, D, K = 2, 4, 16, 3
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 0]], jnp.int32)
+    lens = jnp.asarray([29, 17], jnp.int32)
+    if kernel == "paged_decode":
+        fn = paged_decode
+        q = jax.random.normal(jax.random.PRNGKey(5), (B, Hq, D), jnp.float32)
+    else:
+        fn = paged_verify
+        q = jax.random.normal(jax.random.PRNGKey(5), (B, K, Hq, D),
+                              jnp.float32)
+    for layer in (0, 2):
+        got = fn(q, kp, vp, tables, lens, layer=jnp.int32(layer),
+                 pack_gqa=pack_gqa, **scales)
+        lk, lv, lscales = _layer_of(kp, vp, scales, layer, D)
+        want = fn(q, lk, lv, tables, lens, pack_gqa=pack_gqa, **lscales)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "paged_verify"])
+def test_stacked_pool_tunes_as_one_layer(kernel, tmp_path):
+    """``ops`` builds the tuning context of a stacked pool from one
+    layer's dims, so phi3-mini's served pool (all 32 layers, 40 pages of
+    256) picks the config its single-layer pool picks."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.core import AnalyticalMeasure, Autotuner, TuningCache
+    from repro.core.tuner import SHIPPED_DB
+    from repro.kernels import ops
+
+    cfg = get_config("phi3-mini-3.8b")
+    tuner = Autotuner(
+        cache=TuningCache(cache_dir=str(tmp_path), overlay_path=SHIPPED_DB),
+        backend=AnalyticalMeasure(CHIP), on_miss="tune")
+    B, W, ps = 12, 17, 256
+    pool = (cfg.n_layers, cfg.n_kv_heads, 40, ps, cfg.head_dim)
+    q = (B, cfg.n_heads, cfg.head_dim)
+    if kernel == "paged_verify":
+        q = (B, 4) + q[1:]
+    sds = jax.ShapeDtypeStruct
+    fn = getattr(ops, kernel)
+    picks = []
+    for shape, layer in ((pool, 31), (pool[1:], 0)):
+        jax.eval_shape(
+            lambda q_, k_, v_, t_, l_: fn(q_, k_, v_, t_, l_, layer=layer,
+                                          tuner=tuner),
+            sds(q, jnp.bfloat16), sds(shape, jnp.bfloat16),
+            sds(shape, jnp.bfloat16), sds((B, W), jnp.int32),
+            sds((B,), jnp.int32))
+        ctx, config = tuner.last_dispatch(kernel)
+        picks.append((ctx.signature(), config))
+    assert picks[0] == picks[1]

@@ -406,8 +406,9 @@ def _drain_sched(sched):
 def _scatter_gather_roundtrip(B, lens_np, page_size, seed):
     import jax.numpy as jnp
 
+    from repro.kernels.paged_decode import pool_head_dim
     from repro.kernels.ref import gather_pages
-    from repro.models.attention import _gather_pages_bthd, _scatter_pages
+    from repro.models.attention import _gather_pages_bthd, _write_pages
 
     rng = np.random.default_rng(seed)
     Hkv, D = 2, 8
@@ -420,7 +421,10 @@ def _scatter_gather_roundtrip(B, lens_np, page_size, seed):
         need = -(-int(lens_np[b]) // page_size)
         tables[b, :need] = np.arange(nxt, nxt + need)
         nxt += need
-    pool = jnp.zeros((Hkv, pool_pages, page_size, D), jnp.float32)
+    # Two layers stacked, head dim padded to whole lanes; every write goes
+    # to layer 1, and layer 0 and the padding stay zero.
+    pool = jnp.zeros((2, Hkv, pool_pages, page_size, pool_head_dim(D)),
+                     jnp.float32)
     dense = np.zeros((B, max_len, Hkv, D), np.float32)
     # Write each sequence in two ragged chunks, like chunked prefill.
     tbl = jnp.asarray(tables)
@@ -431,15 +435,17 @@ def _scatter_gather_roundtrip(B, lens_np, page_size, seed):
             if hi == lo:
                 continue
             vals = rng.standard_normal((1, hi - lo, Hkv, D)).astype(np.float32)
-            pool = _scatter_pages(pool, jnp.asarray(vals), tbl[b:b + 1],
-                                  jnp.asarray([lo], jnp.int32))
+            pool = _write_pages(pool, jnp.asarray(vals), tbl[b:b + 1],
+                                jnp.asarray([lo], jnp.int32), 1)
             dense[b, lo:hi] = vals[0]
-    got = np.asarray(_gather_pages_bthd(pool, tbl))     # (B, NB*ps, Hkv, D)
+    assert not np.asarray(pool[0]).any()
+    assert not np.asarray(pool[..., D:]).any()
+    got = np.asarray(_gather_pages_bthd(pool, tbl, 1, D))  # (B, NB*ps, Hkv, D)
     for b in range(B):
         L = int(lens_np[b])
         np.testing.assert_array_equal(got[b, :L], dense[b, :L])
     # ref.gather_pages agrees with the model-side gather (kernel layout).
-    got2 = np.asarray(gather_pages(pool, tbl))          # (B, Hkv, T, D)
+    got2 = np.asarray(gather_pages(pool[1, ..., :D], tbl))  # (B, Hkv, T, D)
     np.testing.assert_array_equal(np.moveaxis(got2, 1, 2), got)
 
 
@@ -527,3 +533,196 @@ def test_paged_engine_requires_supported_arch():
     with pytest.raises(NotImplementedError, match="paged serving"):
         ServingEngine(cfg, params, num_pages=8, page_size=8,
                       max_batch=1, max_seq_len=16, prefill_chunk=4)
+
+
+# ---------------------------------------------------------------------------
+# In-place pools: one step of the model against the formulation it
+# replaced, which sliced each layer's pool out of its stack, wrote the new
+# tokens with a scatter (``.at[].set``) and set the layer back.
+# ---------------------------------------------------------------------------
+
+def _scatter_ref(pages, vals, tables, start):
+    """The replaced write: vals (B, S, Hkv[, D]) at positions start[b] + s
+    of one layer's pool (Hkv, P, page_size[, D]) through the tables."""
+    import jax.numpy as jnp
+    S, ps = vals.shape[1], pages.shape[2]
+    pos = start[:, None] + jnp.arange(S)[None, :]
+    blocks = jnp.clip(pos // ps, 0, tables.shape[1] - 1)
+    page_ids = jnp.take_along_axis(tables, blocks, axis=1)
+    return pages.at[:, page_ids, pos % ps].set(jnp.moveaxis(vals, 2, 0))
+
+
+def _reference_layer(p, h, kind, cfg, opts, pool, tables, start, mode):
+    """One layer over its own pool (Hkv, P, page_size, D), as the model did
+    before: new tokens written by ``_scatter_ref``, the pool read whole
+    (the kernels on a 4-D pool, prefill through an XLA gather)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import ops as kops
+    from repro.kernels.ref import gather_pages
+    from repro.models import attention as ATT
+    from repro.models import lm
+    from repro.models.layers import apply_norm
+
+    B, S = h.shape[:2]
+    positions = start[:, None] + jnp.arange(S)[None, :]
+    pool = dict(pool)
+    hn = apply_norm(p["ln1"], h, cfg, impl=opts.norm_impl)
+    q, k, v = ATT._qkv(p["mix"], hn, cfg, positions)
+    scales = {}
+    if "k_scales" in pool:
+        k, ks, v, vs = ATT._quant_kv_token(k, v)
+        for name, val in (("k_scales", ks), ("v_scales", vs)):
+            scales[name] = _scatter_ref(pool[name][:, :, 0], val, tables,
+                                        start)
+            pool[name] = scales[name][:, :, None]
+    kp = pool["k_pages"] = _scatter_ref(pool["k_pages"], k, tables, start)
+    vp = pool["v_pages"] = _scatter_ref(pool["v_pages"], v, tables, start)
+    if mode == "decode":
+        o = kops.paged_decode(q[:, 0], kp, vp, tables, start + 1,
+                              **scales)[:, None]
+    elif mode == "verify":
+        o = kops.paged_verify(q, kp, vp, tables, start + S, **scales)
+    else:
+        kd = jnp.moveaxis(gather_pages(kp, tables), 1, 2)
+        vd = jnp.moveaxis(gather_pages(vp, tables), 1, 2)
+        if scales:
+            ksd, vsd = (jnp.moveaxis(sc[:, tables].reshape(sc.shape[0], B, -1),
+                                     0, 2)
+                        for sc in (scales["k_scales"], scales["v_scales"]))
+            kd = kd.astype(jnp.float32) * ksd[..., None]
+            vd = vd.astype(jnp.float32) * vsd[..., None]
+        valid = jnp.arange(kd.shape[1])[None, None, :] <= positions[:, :, None]
+        s = jnp.einsum("bskgd,btkd->bkgst", ATT._group(q, cfg.n_kv_heads), kd,
+                       preferred_element_type=jnp.float32)
+        s = jnp.where(valid[:, None, None], s * cfg.head_dim ** -0.5, -1e30)
+        prob = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bkgst,btkv->bskgv", prob.astype(vd.dtype), vd,
+                       preferred_element_type=jnp.float32)
+        o = o.reshape(B, S, cfg.n_heads, cfg.head_dim).astype(h.dtype)
+    h = lm._apply_ffn(p, h + ATT._proj_out(p["mix"], o, cfg),
+                      kind.split("_")[1], cfg, opts)
+    return h, pool
+
+
+def _reference_step(params, cfg, opts, tokens, cache, tables, start, mode):
+    """Logits and cache of one paged step in the replaced formulation: the
+    stacked pools go through the layer scan as ``xs`` and come back as
+    ``ys``, each layer's pool sliced out and written back whole."""
+    import jax
+
+    from repro.models.layers import apply_norm, embed_tokens, logits_out
+
+    h = embed_tokens(params["embed"], tokens, cfg)
+    new_cache = {}
+    for ui, (unit, reps) in enumerate(cfg.scan_plan()):
+
+        def body(h_, xs, unit=unit, reps=reps):
+            pl, cl = xs
+            ncs = {}
+            for i, kind in enumerate(unit):
+                self_ = cl[f"l{i}"]["self"]
+                if reps == 1:
+                    self_ = {n: a[0] for n, a in self_.items()}
+                h_, pool = _reference_layer(pl[f"l{i}"], h_, kind, cfg, opts,
+                                            self_, tables, start, mode)
+                if reps == 1:
+                    pool = {n: a[None] for n, a in pool.items()}
+                ncs[f"l{i}"] = {"self": pool}
+            return h_, ncs
+
+        xs = (params[f"u{ui}"], cache[f"u{ui}"])
+        if reps == 1:
+            h, new_cache[f"u{ui}"] = body(h, xs)
+        else:
+            h, new_cache[f"u{ui}"] = jax.lax.scan(body, h, xs)
+    h = apply_norm(params["final_ln"], h, cfg, impl=opts.norm_impl)
+    logits = logits_out(params["embed"], h, cfg)
+    return (logits[:, 0] if mode == "decode" else logits), new_cache
+
+
+# (tables, resident lengths, tokens per row) of each step; page size 4,
+# page 0 is scratch, page 7 belongs to nobody. Decode lands one token
+# mid-page and one on a page's first slot, verify's 3 tokens cross a page
+# in both rows, and the prefill chunk starts mid-page (as after a prefix
+# hit with small pages) and covers three pages. The last decode and
+# verify rows are inactive (scratch table, length 0).
+_STEPS = {
+    "decode": ([[1, 2, 3, 0], [4, 5, 6, 0], [0, 0, 0, 0]], [5, 8, 0], 1),
+    "verify": ([[1, 2, 3, 0], [4, 5, 6, 0], [0, 0, 0, 0]], [2, 7, 0], 3),
+    "prefill": ([[1, 2, 3, 4]], [3], 6),
+}
+
+
+@pytest.mark.parametrize("reps", [2, 1], ids=["scanned", "one_layer"])
+@pytest.mark.parametrize("mode", ["decode", "prefill", "verify"])
+@pytest.mark.parametrize("kv", [None, "kv8"], ids=["bf16", "kv8"])
+def test_in_place_pool_step_matches_layer_slice_scatter(kv, mode, reps):
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import lm
+    from repro.models.param import init_params
+
+    cfg = dataclasses.replace(_tiny_cfg(), n_layers=reps, dtype="bfloat16")
+    assert cfg.scan_plan() == [(("attn_mlp",), reps)]
+    opts = lm.ForwardOpts(decode_impl="paged", quant=kv)
+    params = init_params(jax.random.PRNGKey(0), lm.lm_specs(cfg))
+    ps, n_pages = 4, 8
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 8))
+
+    def fill(spec):            # resident tokens everywhere, not zeros
+        key = next(keys)
+        if spec.dtype == jnp.int8:
+            return jax.random.randint(key, spec.shape, -127, 128, jnp.int8)
+        if spec.dtype == jnp.float32:                   # kv8 scales
+            return jax.random.uniform(key, spec.shape, jnp.float32,
+                                      0.005, 0.02)
+        return jax.random.normal(key, spec.shape, spec.dtype)
+
+    cache0 = jax.tree.map(fill, lm.paged_cache_specs(
+        cfg, n_pages, ps, kv_dtype=opts.kv_dtype()))
+    rows, lens, S = _STEPS[mode]
+    tables = jnp.asarray(rows, jnp.int32)
+    start = jnp.asarray(lens, jnp.int32)
+    tokens = jax.random.randint(next(keys), (len(rows), S), 1,
+                                cfg.vocab_size)
+    step = {"decode": lm.decode_step_paged, "verify": lm.verify_step_paged,
+            "prefill": lm.prefill_paged}[mode]
+    logits, cache = step(params, cfg, tokens, cache0, tables, start, opts)
+    # The replaced formulation's pools had no lanes past the head dim.
+    unpadded = jax.tree.map(
+        lambda a: a if a.shape[-2] == 1 else a[..., :cfg.head_dim], cache0)
+    want_logits, want_cache = _reference_step(params, cfg, opts, tokens,
+                                              unpadded, tables, start, mode)
+    # Active rows only: an inactive row (length 0) writes and reads the
+    # scratch page, which writes with nothing to write also use, and its
+    # tokens are discarded.
+    active = np.asarray(lens) > 0
+    logits, want_logits = (np.asarray(a, np.float32)[active]
+                           for a in (logits, want_logits))
+    np.testing.assert_array_equal(logits.argmax(-1), want_logits.argmax(-1))
+    np.testing.assert_array_equal(logits, want_logits)
+    # Every resident and new token, by its table, in every layer: the same.
+    # Slots past a row's new length may differ (the in-place write fills
+    # them with copies; nothing reads them), and so may the scratch page.
+    owned = {int(p) for row, n in zip(rows, lens) if n for p in row if p}
+    for got, want, old in zip(jax.tree.leaves(cache),
+                              jax.tree.leaves(want_cache),
+                              jax.tree.leaves(cache0)):
+        got, want, old = (np.asarray(a) for a in (got, want, old))
+        if got.shape[-2] == 1:                          # scale pools
+            got, want, old = got[..., 0, :], want[..., 0, :], old[..., 0, :]
+        else:
+            got, old = got[..., :cfg.head_dim], old[..., :cfg.head_dim]
+        for row, n in zip(rows, lens):
+            for pos in range(n + S if n else 0):
+                page, slot = row[pos // ps], pos % ps
+                np.testing.assert_array_equal(got[:, :, page, slot],
+                                              want[:, :, page, slot])
+        for page in set(range(1, n_pages)) - owned:
+            np.testing.assert_array_equal(got[:, :, page], old[:, :, page])
+            np.testing.assert_array_equal(want[:, :, page], old[:, :, page])
