@@ -31,12 +31,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import weights
+from harness import flops, weights
 
 HIGHEST = jax.lax.Precision.HIGHEST
 BUCKET = 1024         # sequences are padded to a multiple of this
 Q_BLOCK = 512         # attention queries per block
 LOGIT_ROWS = 256      # rows of logits formed at once
+NORMS = ("ln1/w", "ln2/w")   # a layer's norm weights, drawn as ones
 
 
 class Dims(NamedTuple):
@@ -55,7 +56,7 @@ class Dims(NamedTuple):
 def dims(config: Dict) -> Dims:
     d = config["hidden_size"]
     hq, hkv = config["num_attention_heads"], config["num_key_value_heads"]
-    return Dims(d=d, hq=hq, hkv=hkv, dh=d // hq,
+    return Dims(d=d, hq=hq, hkv=hkv, dh=flops.head_dim(config),
                 f=config["intermediate_size"], vocab=config["vocab_size"],
                 layers=config["num_hidden_layers"],
                 eps=config["rms_norm_eps"], theta=config["rope_theta"],
@@ -130,7 +131,8 @@ def _layer_weights(key, dm: Dims, layer, dtype):
     shapes = {"ln1/w": (d,), "mix/wq": (d, hq * dh), "mix/wk": (d, hkv * dh),
               "mix/wv": (d, hkv * dh), "mix/wo": (hq * dh, d),
               "ln2/w": (d,), "ffn/wi": (d, 2 * f), "ffn/wo": (f, d)}
-    w = {name: weights.draw(key, name, layer, shape, dtype)
+    w = {name: weights.draw(key, name, layer, shape, dtype,
+                            init="ones" if name in NORMS else "normal")
          .astype(jnp.float32) for name, shape in shapes.items()}
     return {"ln1": w["ln1/w"], "wq": w["mix/wq"], "wk": w["mix/wk"],
             "wv": w["mix/wv"], "wo": w["mix/wo"], "ln2": w["ln2/w"],
@@ -160,7 +162,8 @@ def _embed(tok, ids):
 def _head(key, out, h, targets, *, dm, low):
     """For each row of h: the best logit, the logits of ``targets`` (K
     candidate tokens per row) and the argmax."""
-    ln = weights.draw(key, "final_ln/w", 0, (dm.d,), jnp.float32)
+    ln = weights.draw(key, "final_ln/w", 0, (dm.d,), jnp.float32,
+                      init="ones")
     x = _rms(h, ln, dm.eps).reshape(-1, LOGIT_ROWS, dm.d)
 
     def rows(args):

@@ -11,12 +11,13 @@ configuration says.
 from __future__ import annotations
 
 import dataclasses
+import typing
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
-from harness import weights
+from harness import flops, weights
 
 # What the program implements, and so what a configuration must state.
 PROGRAM_FIXED = {"rms_norm_eps": 1e-06, "partial_rotary_factor": 1.0,
@@ -24,21 +25,44 @@ PROGRAM_FIXED = {"rms_norm_eps": 1e-06, "partial_rotary_factor": 1.0,
 
 
 def model_config(config: Dict):
-    """``repro.models.config.ModelConfig`` of a configuration file."""
+    """``repro.models.config.ModelConfig`` of a configuration file: a
+    dense decoder of the file's widths, then each field that the file's
+    ``program`` block sets (``{"family": "moe", "moe": {...}}``)."""
     from repro.models.config import ModelConfig
     for k, v in PROGRAM_FIXED.items():
         if config.get(k, v) != v:
             raise ValueError(f"{config['name']}: {k}={config[k]!r}; the "
                              f"program implements {v!r}")
     d, hq = config["hidden_size"], config["num_attention_heads"]
-    return ModelConfig(
+    cfg = ModelConfig(
         name=config["name"], family="dense",
         n_layers=config["num_hidden_layers"], d_model=d, n_heads=hq,
-        n_kv_heads=config["num_key_value_heads"], head_dim=d // hq,
+        n_kv_heads=config["num_key_value_heads"],
+        head_dim=flops.head_dim(config),
         d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
         tie_embeddings=bool(config["tie_word_embeddings"]),
         rope_theta=float(config["rope_theta"]),
         dtype=config["deployment"]["dtype"])
+    if "program" not in config:
+        return cfg
+    return dataclasses.replace(cfg, **_fields(ModelConfig, config["program"],
+                                              config["name"]))
+
+
+def _fields(cls, values: Dict, where: str) -> Dict:
+    """``values`` as fields of the dataclass ``cls``; a dict for a field
+    whose type is a dataclass (``Optional[MoEConfig]``) is built as one."""
+    hints = typing.get_type_hints(cls)
+    out = {}
+    for k, v in values.items():
+        if k not in hints:
+            raise ValueError(f"{where}: {cls.__name__} has no field {k!r}")
+        inner = [t for t in (hints[k], *typing.get_args(hints[k]))
+                 if dataclasses.is_dataclass(t)]
+        if inner and isinstance(v, dict):
+            v = inner[0](**_fields(inner[0], v, f"{where}: {k}"))
+        out[k] = v
+    return out
 
 
 def _leaf_name(path) -> tuple:
@@ -68,15 +92,15 @@ def draw_params(cfg, seed: int):
         name, unit, pos = _leaf_name(path)
         if unit is None:
             return weights.draw(key, name, 0, spec.shape, spec.dtype,
-                                tied=cfg.tie_embeddings)
+                                tied=cfg.tie_embeddings, init=spec.init)
         start, width, reps = first[unit]
         if reps == 1:
             return weights.draw(key, name, start + pos, spec.shape,
-                                spec.dtype)
+                                spec.dtype, init=spec.init)
         layers = start + pos + width * jnp.arange(reps)
         return jax.lax.map(
             lambda l: weights.draw(key, name, l, spec.shape[1:],
-                                   spec.dtype), layers)
+                                   spec.dtype, init=spec.init), layers)
 
     @jax.jit
     def draw_all(key):

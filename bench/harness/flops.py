@@ -4,18 +4,48 @@ A matmul of an (m, k) by a (k, n) operand is 2 m k n operations. Model
 operations count what the model needs, not what the program computes:
 the tokens of a prefill chunk that carry prompt (not its padding), each
 decoded token, and attention over the tokens resident at each position.
+
+The functions here count a dense RoPE/SwiGLU decoder. A configuration of
+another architecture names its own counts module (``counts_for``).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Iterable
+import os
+import sys
+from typing import Dict, Iterable, List, Tuple
+
+from harness import spec
+
+
+def head_dim(config: Dict) -> int:
+    """The file's ``head_dim``; hidden_size / num_attention_heads where it
+    gives none."""
+    dh = config.get("head_dim")
+    if dh is None:
+        dh = config["hidden_size"] // config["num_attention_heads"]
+    return dh
 
 
 def widths(config: Dict) -> Dict[str, int]:
-    d, hq = config["hidden_size"], config["num_attention_heads"]
-    return dict(d=d, hq=hq, hkv=config["num_key_value_heads"], dh=d // hq,
+    return dict(d=config["hidden_size"], hq=config["num_attention_heads"],
+                hkv=config["num_key_value_heads"], dh=head_dim(config),
                 f=config["intermediate_size"], vocab=config["vocab_size"],
                 layers=config["num_hidden_layers"])
+
+
+def counts_for(config: Dict, bench_dir: str = None):
+    """The work counts of a configuration: the module
+    ``bench/configs/<counts>.py`` where the file names ``"counts"``, else
+    this module's dense counts. Either gives ``prefill_flops(config,
+    start, tokens)``, ``decode_flops(config, contexts)`` and
+    ``paged_decode_groups(config, contexts)``."""
+    name = config.get("counts")
+    if name is None:
+        return sys.modules[__name__]
+    return spec.load_module(
+        os.path.join(bench_dir or spec.BENCH_DIR, "configs", name + ".py"),
+        "bench_counts_" + name)
 
 
 def dense_flops_per_token(config: Dict) -> float:
@@ -65,3 +95,11 @@ def paged_decode_cost(config: Dict, contexts: Iterable[int],
     nbytes = (2.0 * w["hkv"] * w["dh"] * keys * kv_bytes
               + 2.0 * len(contexts) * w["hq"] * w["dh"] * kv_bytes)
     return {"flops": flops, "bytes": nbytes}
+
+
+def paged_decode_groups(config: Dict, contexts: Iterable[int]
+                        ) -> List[Tuple[int, Dict[str, float]]]:
+    """(layers, one layer's ``paged_decode_cost``) for each group of
+    layers whose paged attention reads alike: every layer, here."""
+    return [(config["num_hidden_layers"], paged_decode_cost(config,
+                                                            contexts))]

@@ -24,6 +24,7 @@ class Run:
     memory_peak_bytes: int
     events: Optional[Dict] = None       # trace.capture(), traced runs only
     setup_s: Optional[float] = None
+    bench_dir: Optional[str] = None     # where the cell's files are
 
     # -- requests and tokens (host clock) ---------------------------------
     def paused(self, due: float, t: float) -> float:

@@ -3,11 +3,28 @@
   BENCHMARK.json                   the cells, configurations and metrics
   bench/configs/<config>.json      a configuration (the entry's ``file``)
   bench/configs/<reference>.py     its plain reference, named in the file
+                                   as ``"reference"``
+  bench/configs/<counts>.py        its work counts, named in the file as
+                                   ``"counts"``; without it, the dense
+                                   decoder's (``harness/flops.py``)
   bench/traffic/<traffic>.json     a traffic mix (``harness/traffic.py``)
   bench/metrics/<metric>.py        a per-layer metric: ``read(run)``
 
-Adding a configuration, a mix or a metric is a new file and an entry in
-``BENCHMARK.json``; nothing here changes.
+A configuration file gives the published widths (``head_dim`` where it is
+not hidden_size / num_attention_heads) and, in an optional ``program``
+block, any field of the program's ``ModelConfig`` that the dense default
+does not set: ``{"family": "moe", "moe": {"n_experts": 64, ...},
+"window": 1024}`` (``harness/engine.py``). A counts module defines
+
+  prefill_flops(config, start, tokens) -> float   a chunk of ``tokens``
+                                   prompt tokens after ``start`` resident
+  decode_flops(config, contexts) -> float         one token per sequence
+  paged_decode_groups(config, contexts) -> [(n_layers, {"flops", "bytes"})]
+                                   one layer's paged attention for each
+                                   group of layers that reads alike
+
+Adding a configuration, an architecture, a mix or a metric is new files
+and an entry in ``BENCHMARK.json``; nothing here changes.
 """
 
 from __future__ import annotations

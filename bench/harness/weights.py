@@ -5,13 +5,15 @@ layer's index, so the reference can draw one layer at a time and get the
 very values the program serves, without taking anything the program made.
 
   normal leaves:  N(0, 1) * scale, drawn in float32, rounded to the leaf's
-                  dtype; scale 1 / sqrt(fan_in) (rows of the matrix), 1 for
-                  an untied token embedding
+                  dtype; scale 1 / sqrt(fan_in) (rows of a matrix, the
+                  length of a vector), 1 for an untied token embedding
   tied embedding: 1 / sqrt(d_model), the scale of the output head it also
                   serves as; at scale 1 a token's own row would outscore
                   every other by about d_model and make each token predict
                   itself
-  norm weights:   ones
+  ones, zeros:    where the leaf's kind says so (the program's
+                  ``ParamSpec.init``: norm weights, biases); a reference
+                  states the kind of each leaf it draws
 """
 
 from __future__ import annotations
@@ -35,22 +37,28 @@ def base_key(seed: int):
     return key
 
 
-def leaf_init(name: str, shape, tied: bool = False) -> tuple:
-    """(kind, scale) of a leaf: ``ones`` for norm weights, else normal.
+def leaf_init(name: str, shape, tied: bool = False,
+              init: str = "normal") -> tuple:
+    """(kind, scale) of a leaf of kind ``init`` (normal, ones or zeros).
     ``tied``: the token embedding is also the output head."""
-    parts = name.split("/")
-    if len(parts) > 1 and "ln" in parts[-2]:      # ln1, ln2, final_ln
-        return "ones", 1.0
+    if init != "normal":
+        return init, 1.0
     if name == "embed/tok":
         return "normal", float(shape[-1]) ** -0.5 if tied else 1.0
-    return "normal", float(shape[-2]) ** -0.5
+    fan_in = shape[-2] if len(shape) > 1 else shape[-1]
+    return "normal", float(fan_in) ** -0.5
 
 
-def draw(key, name: str, layer, shape, dtype, tied: bool = False):
+def draw(key, name: str, layer, shape, dtype, tied: bool = False,
+         init: str = "normal"):
     """Leaf ``name`` of layer ``layer`` (0 for leaves outside the layers)."""
-    kind, scale = leaf_init(name, shape, tied)
+    kind, scale = leaf_init(name, shape, tied, init)
     if kind == "ones":
         return jnp.ones(shape, dtype)
+    if kind == "zeros":
+        return jnp.zeros(shape, dtype)
+    if kind != "normal":
+        raise ValueError(f"{name}: unknown kind {kind!r}")
     k = jax.random.fold_in(jax.random.fold_in(
         key, zlib.crc32(name.encode()) & 0x7FFFFFFF), layer)
     return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)

@@ -1,7 +1,7 @@
 """step_mfu (%), model step: the model's operations for the prompt and
-decoded tokens of the window's steps (harness/flops.py, from the widths
-and each token's resident context) over the steps' host-clocked time
-times the chip's bf16 peak."""
+decoded tokens of the window's steps (the configuration's counts,
+harness/flops.py, from the widths and each token's resident context) over
+the steps' host-clocked time times the chip's bf16 peak."""
 
 from harness import flops
 
@@ -10,9 +10,10 @@ def read(run):
     steps = run.steps_in_window()
     if not steps:
         return None
-    work = sum(flops.prefill_flops(run.config, s.prefill_start,
-                                   s.prefill_tokens)
-               + flops.decode_flops(run.config, s.decode_ctx)
+    counts = flops.counts_for(run.config, run.bench_dir)
+    work = sum(counts.prefill_flops(run.config, s.prefill_start,
+                                    s.prefill_tokens)
+               + counts.decode_flops(run.config, s.decode_ctx)
                for s in steps)
     busy = sum(s.t1 - s.t0 for s in steps)
     if work <= 0 or busy <= 0:

@@ -188,7 +188,8 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, *,
             f"max {lags[-1] * 1e3:.3f} over {len(lags)} requests")
     run = result.Run(config=cell.config, window=win, peaks=pk,
                      memory_peak_bytes=info["memory_peak_bytes"],
-                     events=events, setup_s=setup_s)
+                     events=events, setup_s=setup_s,
+                     bench_dir=cell.bench_dir)
     wanted = cell.per_layer if traced else cell.end_to_end
     metrics = {}
     for m in wanted:
