@@ -22,6 +22,19 @@ from harness import flops, weights
 # What the program implements, and so what a configuration must state.
 PROGRAM_FIXED = {"rms_norm_eps": 1e-06, "partial_rotary_factor": 1.0,
                  "rope_scaling": None, "hidden_act": "silu"}
+# The one rotation the program applies, wherever ``rope_parameters``
+# states one: at its top level or in a per-layer section
+# (``{"full_attention": {"rope_type": "yarn", ...}}``).
+PROGRAM_ROPE_TYPE = "default"
+
+
+def _rope_types(section: Dict, where: str = "rope_parameters"):
+    """(where, rope_type) of ``section`` and of each section inside it."""
+    if "rope_type" in section:
+        yield f"{where}.rope_type", section["rope_type"]
+    for k, v in section.items():
+        if isinstance(v, dict):
+            yield from _rope_types(v, f"{where}.{k}")
 
 
 def model_config(config: Dict):
@@ -33,6 +46,10 @@ def model_config(config: Dict):
         if config.get(k, v) != v:
             raise ValueError(f"{config['name']}: {k}={config[k]!r}; the "
                              f"program implements {v!r}")
+    for where, v in _rope_types(config.get("rope_parameters") or {}):
+        if v != PROGRAM_ROPE_TYPE:
+            raise ValueError(f"{config['name']}: {where}={v!r}; the "
+                             f"program implements {PROGRAM_ROPE_TYPE!r}")
     d, hq = config["hidden_size"], config["num_attention_heads"]
     cfg = ModelConfig(
         name=config["name"], family="dense",
@@ -49,9 +66,16 @@ def model_config(config: Dict):
                                               config["name"]))
 
 
+def _frozen(v):
+    """A JSON list as a tuple, lists inside it too, so that a per-layer
+    field keeps a frozen config hashable."""
+    return tuple(_frozen(x) for x in v) if isinstance(v, list) else v
+
+
 def _fields(cls, values: Dict, where: str) -> Dict:
     """``values`` as fields of the dataclass ``cls``; a dict for a field
-    whose type is a dataclass (``Optional[MoEConfig]``) is built as one."""
+    whose type is a dataclass (``Optional[MoEConfig]``) is built as one,
+    a list as a tuple."""
     hints = typing.get_type_hints(cls)
     out = {}
     for k, v in values.items():
@@ -61,7 +85,7 @@ def _fields(cls, values: Dict, where: str) -> Dict:
                  if dataclasses.is_dataclass(t)]
         if inner and isinstance(v, dict):
             v = inner[0](**_fields(inner[0], v, f"{where}: {k}"))
-        out[k] = v
+        out[k] = _frozen(v)
     return out
 
 

@@ -25,6 +25,14 @@ does not set: ``{"family": "moe", "moe": {"n_experts": 64, ...},
 
 Adding a configuration, an architecture, a mix or a metric is new files
 and an entry in ``BENCHMARK.json``; nothing here changes.
+
+A cell reports each metric whose entry lists it under ``workloads``, and
+each metric whose entry has no such list. In ``BENCHMARK.json`` only
+``output_tokens_per_s`` and ``setup_s`` have none; ``ttft_p90_ms``,
+``itl_p95_ms`` and every per-layer metric list their cells. So a new cell
+adds its name to each list whose metric it can report: a cell in no list
+reports ``output_tokens_per_s`` and ``setup_s`` alone, and no per-layer
+metric.
 """
 
 from __future__ import annotations
@@ -93,6 +101,9 @@ def metric_reader(bench_dir: str, metric: str):
 
 def reference_module(bench_dir: str, config: Dict):
     """The plain reference that the configuration file names."""
-    ref = config["reference"]
+    ref = config.get("reference")
+    if ref is None:
+        raise ValueError(f"{config['name']}: the configuration names no "
+                         f"reference, so no run of it can be judged")
     return load_module(os.path.join(bench_dir, "configs", ref + ".py"),
                        "bench_reference_" + ref)

@@ -2,12 +2,15 @@
 ``correct`` true for the program as it is and false with its timed path
 broken, the control failing the limit, and no run without a TPU."""
 
+import dataclasses
 import json
 import os
 import shutil
 import subprocess
 import sys
+from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -58,54 +61,111 @@ def test_a_cell_is_files_and_entries(tmp_path, bench_run):
     assert list(out)[-1] == "checks"
 
 
-def add_moe_cell(root):
-    """The smoke sparse-expert decoder with sliding-window layers and its
-    counts module, added to ``root`` as files and entries."""
+# The tests' non-dense configurations: (file, cell, [(layers, contexts)]
+# of the paged attention groups its counts give at contexts [10, 100, 60]).
+ARCHS = {
+    "moe-window": ("smoke-moe-config.json", "smoke-moe.chat",
+                   [(1, [10, 100, 60]), (3, [10, 32, 32])]),
+    "moe-only": ("smoke-moe-only-config.json", "smoke-moe-only.chat",
+                 [(3, [10, 100, 60])]),
+}
+E2E_LISTED = {"ttft_p90_ms", "itl_p95_ms"}
+
+
+def add_moe_cell(root, arch="moe-window", listed=True):
+    """A smoke sparse-expert decoder (``ARCHS``) and its counts module,
+    added to ``root`` as files and entries. ``listed``: the cell's name is
+    added to every metric's ``workloads`` list, as a new cell does."""
+    data, cell, _ = ARCHS[arch]
     configs = os.path.join(root, "bench", "configs")
-    shutil.copy(os.path.join(DATA, "smoke-moe-config.json"),
-                os.path.join(configs, "smoke-moe.json"))
+    shutil.copy(os.path.join(DATA, data),
+                os.path.join(configs, arch + ".json"))
     shutil.copy(os.path.join(DATA, "smoke_moe_counts.py"), configs)
     path = os.path.join(root, "BENCHMARK.json")
     with open(path) as f:
         b = json.load(f)
-    b["configs"].append({"name": "smoke-moe", "source": "tests",
-                         "file": "bench/configs/smoke-moe.json",
+    b["configs"].append({"name": arch, "source": "tests",
+                         "file": f"bench/configs/{arch}.json",
                          "reduced": [], "why": "CPU-sized"})
-    b["workloads"].append({"name": "smoke-moe.chat", "config": "smoke-moe",
+    b["workloads"].append({"name": cell, "config": arch,
                            "traffic": "smoke", "chips": 1, "why": "tests"})
+    for m in b["end_to_end"] + b["per_layer"]:
+        if listed and "workloads" in m:
+            m["workloads"].append(cell)
     with open(path, "w") as f:
         json.dump(b, f)
-    return spec.load_cell("smoke-moe.chat", root)
+    return spec.load_cell(cell, root)
 
 
-def test_a_non_dense_architecture_is_files_and_entries(tmp_path):
-    """Experts, a window, leading dense layers, a head_dim other than
-    hidden / heads, bias vectors and counts of its own enter as files and
-    entries: the harness builds what the file states and stops only where
-    the program's paged path does."""
+def hand_built(conf):
+    """The ``ModelConfig`` that a configuration file states, built here
+    from its widths and its ``program`` block."""
+    from repro.models.config import ModelConfig, MoEConfig
+    program = dict(conf["program"])
+    moe = MoEConfig(**program.pop("moe"))
+    dense = ModelConfig(
+        name=conf["name"], family="dense",
+        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
+        n_heads=conf["num_attention_heads"],
+        n_kv_heads=conf["num_key_value_heads"],
+        head_dim=conf.get("head_dim", conf["hidden_size"]
+                          // conf["num_attention_heads"]),
+        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
+        tie_embeddings=conf["tie_word_embeddings"],
+        rope_theta=conf["rope_theta"], dtype=conf["deployment"]["dtype"])
+    return dataclasses.replace(dense, moe=moe, **program)
+
+
+def deepest_repo_frame(err):
+    """(module, file) of the deepest frame of ``err`` that lies in the
+    repo's own code, the program's or the benchmark's; frames of JAX and
+    other libraries are passed over."""
+    repro_dir = os.path.join(ROOT, "src", "repro")
+    found, tb = None, err.__traceback__
+    while tb is not None:
+        f = tb.tb_frame
+        path = os.path.abspath(f.f_code.co_filename)
+        if path.startswith((repro_dir + os.sep, BENCH + os.sep)):
+            found = (f.f_globals.get("__name__", ""), path)
+        tb = tb.tb_next
+    return found
+
+
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_a_non_dense_architecture_is_files_and_entries(arch, tmp_path):
+    """Experts, with or without a window, leading dense layers, a head_dim
+    other than hidden / heads and counts of its own enter as files and
+    entries: the harness builds the model config that the file states,
+    draws the program's tree as the program specifies it, and stops only
+    where the program does, whatever the program serves."""
     from repro.models import lm
-    from repro.models.config import MoEConfig
+    from repro.models.param import ParamSpec
+    _, _, want_groups = ARCHS[arch]
     root = make_root(tmp_path)
-    cell = add_moe_cell(root)
+    cell = add_moe_cell(root, arch)
     assert_harness_unchanged(root)
     conf = cell.config
     cfg = engine.model_config(conf)
-    assert (cfg.family, cfg.window, cfg.first_dense, cfg.norm) == \
-        ("moe", 32, 1, "layernorm")
-    assert cfg.moe == MoEConfig(n_experts=4, top_k=2, d_ff_expert=32)
-    assert (cfg.head_dim, cfg.d_model // cfg.n_heads) == (32, 16)
-    assert cfg.layer_kinds() == ["attn_mlp"] + ["attn_moe"] * 3
+    assert cfg == hand_built(conf)
 
     params = engine.draw_params(cfg, 11)
-    dense, moe = params["u0"]["l0"], params["u0"]["l1"]
-    assert dense["ffn"]["wi"].shape == (64, 2 * 128)
-    assert moe["ffn"]["wi"].shape == (4, 64, 2 * 32)
-    assert dense["mix"]["wq"].shape == (64, 4 * 32)
-    for leaf in (dense["mix"]["bq"], dense["mix"]["bo"], dense["ln1"]["b"],
-                 moe["mix"]["bv"], moe["ln2"]["b"]):
-        assert not np.asarray(leaf).any()
-    assert (np.asarray(moe["ln1"]["w"]) == 1).all()
-    assert np.asarray(moe["ffn"]["router"]).std() > 0
+    specs = lm.lm_specs(cfg)
+    is_spec = lambda x: isinstance(x, ParamSpec)  # noqa: E731
+    assert jax.tree_util.tree_structure(params) == \
+        jax.tree_util.tree_structure(specs, is_leaf=is_spec)
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    for (path, leaf), s in zip(leaves, jax.tree_util.tree_leaves(
+            specs, is_leaf=is_spec)):
+        where = jax.tree_util.keystr(path)
+        assert (leaf.shape, leaf.dtype) == (tuple(s.shape),
+                                            jnp.dtype(s.dtype)), where
+        a = np.asarray(leaf)
+        if s.init == "ones":
+            assert (a == 1).all(), where
+        elif s.init == "zeros":
+            assert not a.any(), where
+        else:
+            assert a.std() > 0, where
 
     counts = flops.counts_for(conf, cell.bench_dir)
     assert counts is not flops
@@ -115,32 +175,69 @@ def test_a_non_dense_architecture_is_files_and_entries(tmp_path):
         BENCH, "metrics", "paged_decode_roofline.py"), "bench_roofline_moe")
     pk, ctx = peaks.PEAKS["TPU v5 lite"], [10, 100, 60]
     groups = counts.paged_decode_groups(conf, ctx)
-    assert [n for n, _ in groups] == [1, 3]
-    full, windowed = (flops.paged_decode_cost(conf, c)
-                      for c in (ctx, [10, 32, 32]))
-    assert [c for _, c in groups] == [full, windowed]
+    assert groups == [(n, flops.paged_decode_cost(conf, c))
+                      for n, c in want_groups]
     least = [n * max(c["flops"] / pk["bf16_flops_per_s"],
                      c["bytes"] / pk["hbm_bytes_per_s"]) for n, c in groups]
     assert roofline.least_seconds(counts, conf, ctx, pk) == \
         pytest.approx(sum(least))
-    assert least[0] > 0 and least[1] > 0
-    assert counts.decode_flops(conf, ctx) < flops.decode_flops(conf, ctx)
-    assert counts.prefill_flops(conf, 40, 8) < flops.prefill_flops(
-        conf, 40, 8)
+    assert all(t > 0 for t in least)
+    windowed = len(want_groups) > 1
+    assert (counts.decode_flops(conf, ctx) < flops.decode_flops(
+        conf, ctx)) == windowed
+    assert (counts.prefill_flops(conf, 40, 8) < flops.prefill_flops(
+        conf, 40, 8)) == windowed
     assert roofline.kernel_pattern(conf).endswith(
-        ",32\\] custom-call:tpu_custom_call$")
+        f",{cfg.head_dim}\\] custom-call:tpu_custom_call$")
 
-    with pytest.raises(NotImplementedError,
-                       match="paged serving supports") as err:
-        engine.build(conf, 11)
-    assert err.traceback[-1].name == "_check_paged"
-    assert err.traceback[-1].frame.f_globals["__name__"] == lm.__name__
+    try:
+        built = engine.build(conf, 11)
+    except Exception as err:          # the program refuses what it lacks
+        module, path = deepest_repo_frame(err)
+        assert module.split(".")[0] == "repro", (module, path, err)
+        assert not path.startswith(BENCH + os.sep), (path, err)
+    else:
+        assert built.cfg == engine.model_config(conf)
+        assert built.cfg.scan_plan() == hand_built(conf).scan_plan()
+
+
+@pytest.mark.parametrize("listed", [True, False])
+def test_a_new_cell_reports_what_it_is_listed_for(listed, tmp_path):
+    """``ttft_p90_ms``, ``itl_p95_ms`` and every per-layer metric list
+    their cells. A cell added to each list reports every end-to-end and
+    per-layer metric; a cell in no list reports only the metrics that list
+    no cells: today ``output_tokens_per_s`` and ``setup_s``, and no
+    per-layer metric."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        b = json.load(f)
+    e2e = {m["name"] for m in b["end_to_end"]}
+    unlisted = {m["name"] for m in b["end_to_end"] if "workloads" not in m}
+    assert e2e >= E2E_LISTED | {"output_tokens_per_s", "setup_s"}
+    assert unlisted >= {"output_tokens_per_s", "setup_s"}
+    assert not unlisted & E2E_LISTED
+    cell = add_moe_cell(make_root(tmp_path), listed=listed)
+    names = {m["name"] for m in cell.end_to_end}
+    per_layer = [m["name"] for m in cell.per_layer]
+    if listed:
+        assert names == e2e
+        assert per_layer == [m["name"] for m in b["per_layer"]]
+    else:
+        assert names == unlisted
+        assert per_layer == [m["name"] for m in b["per_layer"]
+                             if "workloads" not in m]
+
+
+def test_a_configuration_without_a_reference_cannot_be_judged(tmp_path):
+    cell = add_moe_cell(make_root(tmp_path))
+    assert "reference" not in cell.config
+    with pytest.raises(ValueError, match=cell.config["name"]):
+        spec.reference_module(cell.bench_dir, cell.config)
 
 
 @pytest.mark.parametrize("program,field", [
-    ({"experts": 4}, "experts"),
+    ({"no_such_field": 4}, "no_such_field"),
     ({"moe": {"n_experts": 4, "top_k": 2, "d_ff_expert": 32,
-              "routed": 1}}, "routed"),
+              "no_such_field": 1}}, "no_such_field"),
 ])
 def test_an_unknown_program_field_is_named(program, field):
     with open(os.path.join(DATA, "smoke-config.json")) as f:
@@ -148,6 +245,51 @@ def test_an_unknown_program_field_is_named(program, field):
     conf["program"] = program
     with pytest.raises(ValueError, match=repr(field)):
         engine.model_config(conf)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Inner:
+    sizes: Tuple[int, ...] = ()
+
+
+@dataclasses.dataclass(frozen=True)
+class _Outer:
+    pattern: Tuple[Optional[int], ...] = ()
+    inner: Optional[_Inner] = None
+
+
+def test_a_list_in_the_program_block_is_a_tuple():
+    """A per-layer field stated as a JSON list (nested lists too) is built
+    as a tuple, so the frozen config stays hashable."""
+    got = engine._fields(_Outer, {"pattern": [None, 32, [1, 2]],
+                                  "inner": {"sizes": [4, [5]]}}, "test")
+    assert got == {"pattern": (None, 32, (1, 2)),
+                   "inner": _Inner(sizes=(4, (5,)))}
+    hash(_Outer(**got))
+
+
+@pytest.mark.parametrize("rope", [
+    {"rope_type": "yarn", "factor": 4.0},
+    {"full_attention": {"rope_type": "yarn", "factor": 4.0},
+     "sliding_attention": {"rope_type": "default"}},
+])
+def test_a_rope_the_program_lacks_is_refused(rope):
+    with open(os.path.join(DATA, "smoke-config.json")) as f:
+        conf = json.load(f)
+    conf["rope_parameters"] = rope
+    with pytest.raises(ValueError, match="rope_type='yarn'; the program "
+                                         "implements 'default'"):
+        engine.model_config(conf)
+
+
+def test_the_default_rope_is_accepted():
+    with open(os.path.join(DATA, "smoke-config.json")) as f:
+        conf = json.load(f)
+    plain = engine.model_config(conf)
+    conf["rope_parameters"] = {
+        "rope_type": "default", "rope_theta": conf["rope_theta"],
+        "full_attention": {"rope_type": "default"}}
+    assert engine.model_config(conf) == plain
 
 
 def test_sound_run_is_correct(bench_run, bench_root):  # noqa: F811

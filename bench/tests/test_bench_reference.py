@@ -65,19 +65,26 @@ def test_reference_matches_the_model(arch, ref):
 
 
 def test_program_tree_and_reference_draw_the_same_weights():
+    """Leaves of the engine's tree equal the reference's own draws of the
+    same names, each at the shape and of the kind that the program's
+    ``ParamSpec`` gives."""
+    from repro.models import lm
     cfg = dataclasses.replace(smoke("phi3-mini-3.8b"), dtype="bfloat16")
     params = engine.draw_params(cfg, SEED)
+    specs = lm.lm_specs(cfg)
     key = weights.base_key(SEED)
-    for layer in range(cfg.n_layers):
-        w = weights.draw(key, "ffn/wi", layer, (cfg.d_model, 2 * cfg.d_ff),
-                         jnp.bfloat16)
-        assert np.array_equal(np.asarray(params["u0"]["l0"]["ffn"]["wi"]
-                                         [layer]), np.asarray(w))
+    for name in ("ffn/wi", "ln1/w"):      # stacked over the layers
+        group, leaf = name.split("/")
+        s = specs["u0"]["l0"][group][leaf]
+        for layer in range(cfg.n_layers):
+            w = weights.draw(key, name, layer, s.shape[1:], s.dtype,
+                             init=s.init)
+            assert np.array_equal(np.asarray(
+                params["u0"]["l0"][group][leaf][layer]), np.asarray(w))
+    tok = specs["embed"]["tok"]
     assert np.array_equal(
         np.asarray(params["embed"]["tok"]),
-        np.asarray(weights.draw(key, "embed/tok", 0,
-                                (cfg.vocab_size, cfg.d_model), jnp.bfloat16)))
-    assert (np.asarray(params["u0"]["l0"]["ln1"]["w"]) == 1).all()
+        np.asarray(weights.draw(key, "embed/tok", 0, tok.shape, tok.dtype)))
 
 
 def test_tied_embedding_is_drawn_at_the_head_scale(ref):
